@@ -100,8 +100,8 @@ def run(grid=(64, 64), iters: int = 100, tune_iters: int = 20,
 
 
 def _device_kind() -> str:
-    import jax
-    return jax.default_backend()
+    from repro.core.plan import current_device_kind
+    return current_device_kind()
 
 
 def main(argv=None) -> int:

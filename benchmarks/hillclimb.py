@@ -37,7 +37,7 @@ def parse_override(s: str):
 
 
 def run(arch: str, shape: str, overrides: dict, multi_pod=False,
-        device_kind: str = "tpu") -> dict:
+        device_kind: str = "TPU v5 lite") -> dict:
     import jax
     from repro.core.plan import DEVICE_PROFILES
     from repro.launch import hlo_cost
@@ -97,7 +97,7 @@ def main():
     ap.add_argument("--shape", required=True)
     ap.add_argument("--set", nargs="*", default=[])
     ap.add_argument("--multipod", action="store_true")
-    ap.add_argument("--device-kind", default="tpu",
+    ap.add_argument("--device-kind", default="TPU v5 lite",
                     help="DEVICE_PROFILES row to price the roofline against")
     args = ap.parse_args()
     _force_host_devices()

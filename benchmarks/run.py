@@ -91,6 +91,9 @@ def main() -> int:
     args = ap.parse_args()
     only = ({_ALIASES.get(o, o) for o in args.only} if args.only else None)
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from benchmarks import (adjoint_bench, autotune_bench, fig5_shapes,
                             fig6_3d, multigrid_bench, roofline, scaling_bench,
                             serving_bench, stencil_fuse_sweep, table1_2d)

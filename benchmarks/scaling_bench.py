@@ -16,11 +16,14 @@ the communication-avoiding fuse sweep this repo adds:
   * **equivalence** — a converged fused distributed solve against the
     single-device reference solve (max abs error).
 
-The measurements need more than one device, and ``XLA_FLAGS=
+The measurements need eight devices.  On a CPU host ``XLA_FLAGS=
 --xla_force_host_platform_device_count=8`` must be set before jax imports —
-so ``run()`` (the benchmarks/run.py section) spawns a child process
-(``--child``) and parses its JSON back.  Metric keys are prefixed
-``scaling/`` and land in BENCH_stencil.json's schema-5 ``scaling`` section.
+so there ``run()`` (the benchmarks/run.py section) spawns a child process
+(``--child``) and parses its JSON back.  On an accelerator it never forks (a
+child could not reach the chip the parent holds): it runs in-process on the
+devices present, or skips with a message when there are fewer than eight.
+Metric keys are prefixed ``scaling/`` and land in BENCH_stencil.json's
+schema-5 ``scaling`` section.
 
 CLI:
 
@@ -163,11 +166,24 @@ def _spawn_child(mode: str, cfg: dict, timeout: int = 1800) -> dict:
 def run(smoke: bool = False):
     """The benchmarks/run.py section: (csv rows, ``scaling/``-keyed metrics).
 
-    Spawns the forced-8-device child; every metric row lands in the JSON
+    On a CPU host this spawns the forced-8-device child; on an accelerator
+    it runs in this process on the devices present, or skips (no rows) when
+    there are fewer than eight.  Every metric row lands in the JSON
     artifact's ``scaling`` section (schema 5).
     """
+    import jax
+
     from benchmarks.common import csv_row
-    metrics = _spawn_child("child", {"smoke": smoke})
+    devices = jax.devices()
+    if devices[0].platform == "cpu":
+        metrics = _spawn_child("child", {"smoke": smoke})
+    elif len(devices) >= _DEVICES:
+        metrics = _child({"smoke": smoke})
+    else:
+        print(f"# scaling: skipped — the 2x4 mesh needs {_DEVICES} devices "
+              f"and this host has {len(devices)} {devices[0].device_kind}",
+              file=sys.stderr)
+        return [], {}
     rows = []
     for name in sorted(metrics):
         m = metrics[name]

@@ -392,8 +392,9 @@ def schedule_candidates(
     run interpreted only one schedule is measured — the row exists to be
     *recorded as interpreted*, not to compete.
     """
-    from repro.core.plan import BACKENDS, backend_support
-    from repro.kernels.tiling import default_interpret, resident_fits
+    from repro.core.plan import (BACKENDS, _pallas_fits, backend_support,
+                                 device_profile)
+    from repro.kernels.tiling import default_interpret
 
     interp = default_interpret(None)
     out: list[Candidate] = []
@@ -401,7 +402,7 @@ def schedule_candidates(
         if backend in ("reference", "halo"):
             continue
         if not backend_support(backend, spec, grid_shape=grid_shape,
-                               mode=mode, bc=bc):
+                               mode=mode, bc=bc, device_kind=device_kind):
             continue
         sweeps = backend in ("pallas", "pallas_fused") and spec.ndim == 2 \
             and not spec.is_variable
@@ -416,7 +417,8 @@ def schedule_candidates(
                 if iters % fuse:
                     continue
                 out.append(Candidate(backend, fuse, block_h, "trapezoid"))
-        if resident_fits(grid_shape):
+        if _pallas_fits(spec, grid_shape, 1, device_profile(device_kind),
+                        rim="resident"):
             for fuse in RESIDENT_FUSE_CANDIDATES:
                 if iters % fuse:
                     continue
@@ -443,9 +445,9 @@ def measure_candidate(
     ``mesh`` is required for (and only used by) halo candidates; the entry
     records its (n_row, n_col) tiling so lookups stay mesh-exact.
     """
-    from repro.core.plan import _mesh_tiling, make_plan
+    from repro.core.plan import _mesh_tiling, current_device_kind, make_plan
     if device_kind is None:
-        device_kind = jax.default_backend()
+        device_kind = current_device_kind()
     plan = make_plan(
         spec, grid_shape, backend=cand.backend, bc=bc, mode=mode,
         iters=iters, fuse=cand.fuse if cand.rim or cand.fuse > 1 else None,

@@ -25,8 +25,8 @@ from __future__ import annotations
 import dataclasses
 import enum
 
+import jax
 import jax.numpy as jnp
-import numpy as np
 
 
 class BoundaryMode(enum.Enum):
@@ -47,16 +47,12 @@ class DirichletBC:
 
     def interior_mask(self, shape: tuple[int, ...], dtype=jnp.float32) -> jnp.ndarray:
         """1 in the interior, 0 on the boundary shell (paper §3 'mask')."""
-        m = np.zeros(shape, dtype=np.float32)
-        inner = tuple(slice(1, -1) for _ in shape)
-        m[inner] = 1.0
-        return jnp.asarray(m, dtype=dtype)
+        return interior_mask(shape, dtype)
 
     def bc_grid(self, shape: tuple[int, ...], dtype=jnp.float32) -> jnp.ndarray:
         """BC values on the boundary shell, 0 in the interior."""
         if isinstance(self.value, (int, float)):
-            g = np.full(shape, float(self.value), dtype=np.float32)
-            g = jnp.asarray(g, dtype=dtype)
+            g = jnp.full(shape, float(self.value), dtype)
         else:
             g = jnp.asarray(self.value, dtype=dtype)
             if g.shape != shape:
@@ -77,6 +73,19 @@ class DirichletBC:
         return x * mask + bc
 
 
+def interior_mask(shape: tuple[int, ...], dtype=jnp.float32) -> jnp.ndarray:
+    """1 in the interior, 0 on the boundary shell, computed from iotas.
+
+    Under ``jit`` this is a few fused compares, not a grid-sized constant:
+    a constant mask of a 16384^2 grid would put a gigabyte into the
+    compiled program."""
+    inside = jnp.ones(shape, bool)
+    for d, n in enumerate(shape):
+        i = jax.lax.broadcasted_iota(jnp.int32, shape, d)
+        inside = inside & (i >= 1) & (i < n - 1)
+    return inside.astype(dtype)
+
+
 def runtime_bc_grids(shape: tuple[int, ...], bc_value,
                      dtype=jnp.float32) -> tuple[jnp.ndarray, jnp.ndarray]:
     """(interior_mask, bc_grid) for a possibly-*traced* Dirichlet value.
@@ -88,9 +97,7 @@ def runtime_bc_grids(shape: tuple[int, ...], bc_value,
     ``bc_value``, so gradients flow through it (the adjoint solve needs
     d(solution)/d(boundary value)).
     """
-    m = np.zeros(shape, dtype=np.float32)
-    m[tuple(slice(1, -1) for _ in shape)] = 1.0
-    mask = jnp.asarray(m, dtype)
+    mask = interior_mask(shape, dtype)
     v = jnp.asarray(bc_value, dtype)
     if v.ndim not in (0, len(shape)):
         raise ValueError(

@@ -42,7 +42,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.boundary import DirichletBC
 from repro.core.stencil import StencilSpec, WeightField
-from repro.parallel.halo import exchange_halo_2d, shard_map_compat
+from repro.parallel.halo import auto_axes, exchange_halo_2d
 
 # One exchange_halo_2d call = two directions x two mesh axes.
 HALO_PHASES_PER_EXCHANGE = 4
@@ -119,6 +119,7 @@ def make_halo_runner(mesh, spec: StencilSpec, *, H: int, W: int,
     """
     if spec.ndim != 2:
         raise ValueError("distributed jacobi is 2D (the paper's fig-5 path)")
+    mesh = auto_axes(mesh)
     r = spec.radius
     n_row = mesh.shape[row_axis]
     n_col = mesh.shape[col_axis]
@@ -238,7 +239,8 @@ def make_halo_runner(mesh, spec: StencilSpec, *, H: int, W: int,
     in_spec = P(batch_axis, row_axis, col_axis)
     field_spec = P(None, row_axis, col_axis)
     in_specs = (in_spec, field_spec) if var_fields is not None else (in_spec,)
-    fn = shard_map_compat(local_fn, mesh, in_specs, in_spec)
+    fn = jax.shard_map(local_fn, mesh=mesh, in_specs=in_specs,
+                       out_specs=in_spec, check_vma=False)
 
     def run(x0):
         bc = DirichletBC(bc_value)

@@ -76,11 +76,14 @@ def backend_support(
     mode: BoundaryMode = BoundaryMode.MASK,
     bc: DirichletBC | float | None = 0.0,
     mesh=None,
+    device_kind: str | None = None,
 ) -> BackendSupport:
     """Is ``backend`` legal for this (spec, grid, mode, bc) cell?
 
     Returns a BackendSupport whose ``reason`` string is suitable for a test
     skip message — the conformance matrix relies on this being exhaustive.
+    ``device_kind`` (None: this process's device) sets the scoped VMEM a
+    Pallas kernel must fit.
     """
     if backend not in BACKENDS:
         return _no(f"unknown backend {backend!r} (known: {BACKENDS})")
@@ -149,6 +152,14 @@ def backend_support(
         if not scalar_bc:
             return _no("Pallas kernels pin the shell to a scalar bc_value; "
                        "array-valued DirichletBC unsupported")
+        device = device_profile(device_kind)
+        if grid_shape is not None and not _pallas_fits(spec, grid_shape, 1,
+                                                       device):
+            return _no(f"even the shallowest Pallas block of a "
+                       f"{tuple(grid_shape)} grid needs more than the "
+                       f"{device.scoped_vmem_bytes} bytes of scoped VMEM a "
+                       f"{device.kind} kernel gets (kernels/tiling.py "
+                       f"fits_vmem)")
         return _OK
 
     if backend == "halo":
@@ -172,6 +183,18 @@ def backend_support(
         return _OK
 
     raise AssertionError(backend)
+
+
+def _pallas_fits(spec: StencilSpec, grid_shape, fuse: int,
+                 device: "DeviceProfile", *, block_h: int | None = None,
+                 rim: str | None = None, itemsize: int = 4) -> bool:
+    """Whether the Pallas kernel for this cell fits ``device``'s scoped
+    VMEM at temporal depth ``fuse``."""
+    from repro.kernels.tiling import fits_vmem
+    return fits_vmem(tuple(grid_shape), fuse, spec.radius,
+                     budget=device.scoped_vmem_bytes, itemsize=itemsize,
+                     planes=1 + spec.num_variable_taps, block_h=block_h,
+                     rim=rim or "trapezoid")
 
 
 def _halo_fuse_legal(fuse: int, spec: StencilSpec,
@@ -218,19 +241,51 @@ class DeviceProfile:
     matmul_flops: float   # MXU / GEMM FLOP/s
     mem_bw: float         # HBM / DRAM bytes/s
     pallas_native: bool   # False => Pallas runs in interpret mode
+    scoped_vmem_bytes: int  # VMEM the compiler grants one Pallas kernel
     collective_bw: float = 5e10  # inter-device (ICI/NVLink/net) bytes/s
 
 
+# Keyed by ``jax.Device.device_kind``.  A device that is not here is an
+# error (``device_profile``), never a silent default.
+_MiB = 1 << 20
+
 DEVICE_PROFILES = {
-    # One CPU core; Pallas falls back to the (slow) interpreter.
+    # One CPU core; Pallas falls back to the (slow) interpreter, which has
+    # no VMEM.  Its budget is the v5e's, so a plan that runs here also
+    # compiles on the chip.
     "cpu": DeviceProfile("cpu", 5e10, 2e11, 5e10, pallas_native=False,
-                         collective_bw=1e9),
-    "gpu": DeviceProfile("gpu", 2e13, 1.5e14, 2e12, pallas_native=True,
-                         collective_bw=1e11),
-    # v5e-class: the ~240 FLOP/byte ridge the kernel docstrings cite.
-    "tpu": DeviceProfile("tpu", 4e12, 2e14, 8e11, pallas_native=True,
-                         collective_bw=5e10),
+                         scoped_vmem_bytes=16 * _MiB, collective_bw=1e9),
+    # TPU v5e.  Published (Google Cloud documentation, "TPU v5e"):
+    # 197 TFLOP/s bf16 matmul, 819 GB/s HBM, 1,600 Gbit/s interchip
+    # interconnect.  The VPU rate is not published; 4e12 is an estimate
+    # that puts the ridge near the ~240 FLOP/byte the kernels cite.  The
+    # scoped VMEM is the compiler's default limit, which its out-of-VMEM
+    # errors quote.
+    "TPU v5 lite": DeviceProfile("TPU v5 lite", 4e12, 197e12, 819e9,
+                                 pallas_native=True,
+                                 scoped_vmem_bytes=16 * _MiB,
+                                 collective_bw=2e11),
 }
+
+
+def current_device_kind() -> str:
+    """The ``device_kind`` of the first device JAX sees (what this process
+    runs on) — the identity every profile and tuned-table lookup uses."""
+    return jax.devices()[0].device_kind
+
+
+def device_profile(device_kind: str | None = None) -> DeviceProfile:
+    """The roofline rates of ``device_kind`` (None: this process's
+    device); an unknown kind raises instead of borrowing another's peaks."""
+    kind = current_device_kind() if device_kind is None else device_kind
+    try:
+        return DEVICE_PROFILES[kind]
+    except KeyError:
+        raise ValueError(
+            f"no device profile for device_kind {kind!r} (known: "
+            f"{sorted(DEVICE_PROFILES)}); add its published peaks to "
+            f"core/plan.py DEVICE_PROFILES") from None
+
 
 # Interpret-mode Pallas re-traces every lane op in Python — orders of
 # magnitude off; the model only needs it to never win on CPU.
@@ -242,11 +297,16 @@ _INTERPRET_PENALTY = 1e4
 _PERMUTE_LATENCY = 2.5e-6
 
 
-def _resolve_fuse(iters: int) -> int:
-    """The fuse depth pallas_fused actually runs at for ``iters`` (the same
-    rule make_plan applies) — the cost model must price this, not a phantom
-    deeper fusion."""
-    return next((f for f in (8, 4, 2) if iters % f == 0), 1)
+def _resolve_fuse(iters: int, spec: StencilSpec, grid_shape,
+                  device: DeviceProfile, *, block_h: int | None = None,
+                  rim: str | None = None, itemsize: int = 4) -> int:
+    """The default depth of pallas_fused for ``iters``: the deepest of 8, 4,
+    2 that divides it and fits ``device``'s scoped VMEM, else 1.  make_plan
+    runs this depth and the cost model prices it — never a phantom deeper
+    fusion."""
+    return next((f for f in (8, 4, 2) if iters % f == 0 and _pallas_fits(
+        spec, grid_shape, f, device, block_h=block_h, rim=rim,
+        itemsize=itemsize)), 1)
 
 
 def estimate_seconds(
@@ -303,7 +363,9 @@ def estimate_seconds(
         compute = flops * n / device.vector_flops
         mem = stream / device.mem_bw
         if fuse is None:
-            fuse = _resolve_fuse(iters) if backend == "pallas_fused" else 1
+            fuse = _resolve_fuse(iters, spec, grid_shape, device,
+                                 itemsize=itemsize) \
+                if backend == "pallas_fused" and spec.ndim == 2 else 1
         if backend in ("pallas", "pallas_fused") and fuse > 1 and spec.ndim == 2:
             from repro.kernels.tiling import fuse_redundancy
             mem /= fuse  # fuse-depth fewer HBM round-trips ...
@@ -376,9 +438,8 @@ def choose_backend(
     this choice will be forced into interpret mode, so the Pallas paths are
     priced with the interpreter penalty regardless of the device profile.
     """
-    if device_kind is None:
-        device_kind = jax.default_backend()
-    device = DEVICE_PROFILES.get(device_kind, DEVICE_PROFILES["cpu"])
+    device = device_profile(device_kind)
+    device_kind = device.kind
     mesh_shape = _mesh_tiling(mesh) if mesh is not None else None
 
     # -- measured table first ---------------------------------------------
@@ -396,7 +457,8 @@ def choose_backend(
             if e.backend == "halo" and mesh is None:
                 continue
             if not backend_support(e.backend, spec, grid_shape=grid_shape,
-                                   mode=mode, bc=bc, mesh=mesh):
+                                   mode=mode, bc=bc, mesh=mesh,
+                                   device_kind=device_kind):
                 continue
             measured[e.backend] = e.seconds(iters)
         if measured:
@@ -411,7 +473,7 @@ def choose_backend(
         if b == "reference":
             continue
         if not backend_support(b, spec, grid_shape=grid_shape, mode=mode,
-                               bc=bc, mesh=mesh):
+                               bc=bc, mesh=mesh, device_kind=device_kind):
             continue
         costs[b] = estimate_seconds(b, spec, grid_shape, iters, device,
                                     fuse=fuse,
@@ -602,7 +664,7 @@ def make_plan(
         from repro.core import autotune
         table = autotune.resolve_table(tuned)
         entry = table.lookup(
-            device_kind or jax.default_backend(), autotune.spec_family(spec),
+            device_profile(device_kind).kind, autotune.spec_family(spec),
             tuple(grid_shape), autotune.dtype_key(dtype),
             mesh_shape=_mesh_tiling(mesh) if mesh is not None else None) \
             if table else None
@@ -618,9 +680,11 @@ def make_plan(
             if rim is None:
                 rim = entry.rim
     sup = backend_support(backend, spec, grid_shape=grid_shape, mode=mode,
-                          bc=bc, mesh=mesh)
+                          bc=bc, mesh=mesh, device_kind=device_kind)
     if not sup:
         raise ValueError(f"backend {backend!r} unsupported here: {sup.reason}")
+    device = device_profile(device_kind)
+    itemsize = jnp.dtype(dtype).itemsize
 
     # ``fuse`` is a hint for the 2D Pallas paths (both scalar-bc and raw
     # execute in fuse-sized chunks) and for halo (one deep-halo exchange per
@@ -641,12 +705,23 @@ def make_plan(
         elif fuse is None:
             if rim == "resident":
                 fuse = iters  # the whole chunk stays resident in VMEM
+            elif backend == "pallas_fused":
+                fuse = _resolve_fuse(iters, spec, grid_shape, device,
+                                     block_h=block_h, rim=rim,
+                                     itemsize=itemsize)
             else:
-                fuse = _resolve_fuse(iters) if backend == "pallas_fused" else 1
+                fuse = 1
         elif iters % fuse:
             raise ValueError(f"iters={iters} not divisible by fuse={fuse}")
         if fusing and rim is None and fuse > 1:
             rim = "trapezoid"
+        if fusing and spec.ndim == 2 and not _pallas_fits(
+                spec, grid_shape, fuse, device, block_h=block_h, rim=rim,
+                itemsize=itemsize):
+            raise ValueError(
+                f"fuse={fuse} {rim or 'trapezoid'} blocks of a {grid_shape} "
+                f"grid need more than the scoped VMEM a {device.kind} "
+                f"kernel gets; use a shallower fuse")
 
     from repro.kernels.tiling import default_interpret
     interpreted = backend in ("pallas", "pallas_fused") \

@@ -55,7 +55,8 @@ Two entry kinds:
   way via :meth:`PlanCache.multigrid`.
 
 Stats (hits / misses / evictions / rebuilds / compile-seconds) are surfaced
-on the cache object; corrupt entries are evicted and rebuilt once.  The
+on the cache object; corrupt entries (a lost solver) are evicted and rebuilt
+once, while a solver that fails — to compile, say — raises.  The
 module-level :func:`default_plan_cache` is the process-wide instance that
 ``core.adjoint`` and ``serve.engine`` share.
 """
@@ -145,8 +146,8 @@ class CachedSolver:
     trace-safe core the adjoint machinery calls.  For a bucketed entry both
     embed the request in the bucket grid (module docstring) and slice the
     result back to the original shape; for an exact entry they delegate
-    directly.  A call that blows up inside the cached object evicts and
-    rebuilds the entry once before re-raising.
+    directly.  An entry found without its solver is evicted and rebuilt
+    once; errors from a healthy entry surface to the caller.
     """
 
     def __init__(self, cache: "PlanCache", entry: _Entry, builder,
@@ -322,12 +323,13 @@ class CachedSolver:
     # -- degradation: evict + rebuild a corrupt entry once -----------------
 
     def _attempt(self, fn):
-        try:
-            return fn(self._entry.obj)
-        except Exception:
+        """Run ``fn`` on the cached solver.  An entry that lost its solver
+        (corrupt) is evicted and rebuilt once; an error raised by a healthy
+        entry — a compile failure on the chip among them — surfaces."""
+        if not hasattr(self._entry.obj, "solve"):
             self._entry = self._cache._replace(self._entry.key, self._builder)
             self.backend = self._entry.backend
-            return fn(self._entry.obj)
+        return fn(self._entry.obj)
 
     # -- public API --------------------------------------------------------
 
@@ -476,13 +478,14 @@ class PlanCache:
     def _bucket_backend(self, template: StencilSpec, bucket, dtype,
                         interpret, device_kind) -> str:
         from repro.core import autotune
-        from repro.core.plan import (DEVICE_PROFILES, backend_support,
+        from repro.core.plan import (backend_support, device_profile,
                                      estimate_seconds, make_plan)
         nd = template.ndim
         cands = [b for b in _PAD_BACKENDS.get(nd, ("reference",))
                  if backend_support(b, template, grid_shape=bucket,
                                     mode=BoundaryMode.MASK,
-                                    bc=DirichletBC(0.0))]
+                                    bc=DirichletBC(0.0),
+                                    device_kind=device_kind)]
         if not cands:
             return "reference"
         if len(cands) == 1:
@@ -495,21 +498,21 @@ class PlanCache:
                 return self._probe_winners[memo_key]
 
         if not self.probe:
+            device = device_profile(device_kind)
             table = autotune.resolve_table(self.tuned)
             if table is not None and len(table):
                 entry = table.lookup(
-                    device_kind or jax.default_backend(),
-                    autotune.spec_family(template), tuple(bucket),
-                    autotune.dtype_key(dtype))
+                    device.kind, autotune.spec_family(template),
+                    tuple(bucket), autotune.dtype_key(dtype))
                 if entry is not None and entry.backend in cands:
                     return entry.backend
-            device = DEVICE_PROFILES.get(
-                device_kind or jax.default_backend(), DEVICE_PROFILES["cpu"])
             return min(cands, key=lambda b: estimate_seconds(
                 b, template, tuple(bucket), 100, device))
 
         # Measured probe: a short var-operand plan per candidate, timed
-        # after one warmup (the warmup absorbs compilation).
+        # after one warmup (the warmup absorbs compilation).  A candidate
+        # that fails to compile or run raises: on the chip that is a fault
+        # to surface, not a candidate to drop.
         t_probe = time.perf_counter()
         fields = jnp.asarray(template.field_stack(), dtype)
         x = jnp.zeros((1,) + tuple(bucket), dtype)
@@ -517,20 +520,17 @@ class PlanCache:
         bcg = jnp.zeros(tuple(bucket), dtype)
         best, best_t = cands[0], float("inf")
         for cand in cands:
-            try:
-                plan = make_plan(template, tuple(bucket), backend=cand,
-                                 bc=DirichletBC(0.0), mode=BoundaryMode.MASK,
-                                 iters=self.probe_iters, dtype=dtype,
-                                 interpret=interpret,
-                                 device_kind=device_kind, tuned=None)
-                jax.block_until_ready(
-                    plan(x, fields=fields, source=src, bc_value=bcg))
-                t0 = time.perf_counter()
-                jax.block_until_ready(
-                    plan(x, fields=fields, source=src, bc_value=bcg))
-                dt_c = time.perf_counter() - t0
-            except Exception:
-                continue
+            plan = make_plan(template, tuple(bucket), backend=cand,
+                             bc=DirichletBC(0.0), mode=BoundaryMode.MASK,
+                             iters=self.probe_iters, dtype=dtype,
+                             interpret=interpret, device_kind=device_kind,
+                             tuned=None)
+            jax.block_until_ready(
+                plan(x, fields=fields, source=src, bc_value=bcg))
+            t0 = time.perf_counter()
+            jax.block_until_ready(
+                plan(x, fields=fields, source=src, bc_value=bcg))
+            dt_c = time.perf_counter() - t0
             if dt_c < best_t:
                 best, best_t = cand, dt_c
         with self._lock:

@@ -46,9 +46,10 @@ import numpy as np
 
 from repro.core.boundary import BoundaryMode, DirichletBC
 from repro.core.plan import (
-    DEVICE_PROFILES,
     StencilPlan,
+    _pallas_fits,
     choose_backend,
+    device_profile,
     estimate_seconds,
     make_plan,
 )
@@ -115,8 +116,7 @@ def select_fuse(backend: str, spec: StencilSpec, grid_shape: tuple[int, ...],
     if not halo and (backend not in ("pallas", "pallas_fused")
                      or spec.ndim != 2):
         return None
-    if device_kind is None:
-        device_kind = jax.default_backend()
+    device = device_profile(device_kind)
 
     mesh_shape = deepest = None
     if halo:
@@ -132,7 +132,7 @@ def select_fuse(backend: str, spec: StencilSpec, grid_shape: tuple[int, ...],
     from repro.core import autotune
     table = autotune.resolve_table(tuned)
     if table is not None and len(table):
-        entry = table.lookup(device_kind, autotune.spec_family(spec),
+        entry = table.lookup(device.kind, autotune.spec_family(spec),
                              tuple(grid_shape), autotune.dtype_key(dtype),
                              mesh_shape=mesh_shape)
         if entry is not None and entry.backend == backend and entry.fuse >= 1:
@@ -141,11 +141,12 @@ def select_fuse(backend: str, spec: StencilSpec, grid_shape: tuple[int, ...],
                 f = min(f, deepest)
             while check_every % f:
                 f -= 1
-            return f
+            if halo or _pallas_fits(spec, grid_shape, f, device):
+                return f
 
-    device = DEVICE_PROFILES.get(device_kind, DEVICE_PROFILES["cpu"])
     candidates = [f for f in _FUSE_CANDIDATES if check_every % f == 0
-                  and (deepest is None or f <= deepest)]
+                  and (deepest is None or f <= deepest)
+                  and (halo or _pallas_fits(spec, grid_shape, f, device))] or [1]
     return min(candidates,
                key=lambda f: estimate_seconds(backend, spec, grid_shape,
                                               check_every, device, fuse=f,
@@ -256,7 +257,7 @@ class Solver:
         table = autotune.resolve_table(tuned)
         if table is not None and len(table):
             entry = table.lookup(
-                device_kind or jax.default_backend(),
+                device_profile(device_kind).kind,
                 autotune.spec_family(spec), self.grid_shape,
                 autotune.dtype_key(dtype),
                 mesh_shape=_mesh_tiling(mesh) if mesh is not None else None)
@@ -393,11 +394,10 @@ class Solver:
             residual = np.asarray(res)
             history = np.asarray(hist)[: int(k)]
 
-        device = DEVICE_PROFILES.get(
-            self.device_kind or jax.default_backend(), DEVICE_PROFILES["cpu"])
         est = estimate_seconds(
             self.backend, self.spec, self.grid_shape,
-            max(int(iterations.max()), 1), device, fuse=self.fuse,
+            max(int(iterations.max()), 1), device_profile(self.device_kind),
+            fuse=self.fuse,
             mesh_shape=self.mesh_shape)
 
         if squeeze:

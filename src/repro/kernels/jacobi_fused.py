@@ -10,25 +10,22 @@ before writing back, multiplying arithmetic intensity by ~T at the cost of
 O(T·r) redundant rim compute (the classic trapezoid/overlapped-tiling
 scheme).
 
-Correctness of the trapezoid: after iteration t, only points ≥ (T−t)·r rows
-inside the block rim are valid — the final (block_h, W) centre is exactly
-valid after T iterations.  In-array interior points never read out-of-array
-points (the Dirichlet shell separates them), so the rim garbage never
-propagates inward; the shell itself is re-pinned to the BC value every
-iteration by the fused mask trick.
+Block geometry (``tiling.fused_block_geometry``): blocks carry whole rows,
+so the TPU compiler's (sublane, 128-lane) rule holds for any width and the
+zero column halo is rebuilt in VMEM every iteration.  A grid that fits one
+row block runs *resident*: its zero row rim is rebuilt the same way, so no
+work is redundant and T is unbounded (the closest TPU analogue of the WSE's
+grid-stays-in-SRAM execution).  Larger grids tile rows into ``bh``-row
+blocks extended by T·r halo rows read from the two neighbouring aligned halo
+blocks — the trapezoid: every iteration spoils r more rows at each end of
+the extended block (they miss their outer neighbours), so after T the
+``bh`` centre rows are exactly valid.  Out-of-array rows are re-zeroed and
+the Dirichlet shell re-pinned every iteration (the fused mask trick).
+Small instances are batched several to a block.
 
-Two rim strategies (``rim=``, searched by the autotuner):
-
-  "trapezoid"  the scheme above — overlapping row blocks, halo T·r deep,
-               O(T·r) redundant rim recompute per block;
-  "resident"   the whole grid lives in ONE VMEM block (the closest TPU
-               analogue of the WSE's grid-stays-in-SRAM execution): the
-               out-of-grid rim is re-zeroed between in-kernel iterations
-               instead of being carried in a deeper halo, so there is no
-               redundant compute and *no geometric limit on T* — depths the
-               trapezoid rejects (T > block_h/r, or any T with a halo wider
-               than the block) are legal.  Only valid when the padded grid
-               fits VMEM (``tiling.resident_fits``).
+The iteration is a ``fori_loop`` over a fixed-shape block, so compile time
+does not grow with T.  This one kernel also serves the single step of
+``stencil2d`` (T=1, input shell left as given).
 """
 from __future__ import annotations
 
@@ -41,115 +38,131 @@ from jax.experimental import pallas as pl
 
 from repro.core.stencil import StencilSpec, WeightField
 from repro.kernels.tiling import (
+    batch_block,
     default_interpret,
     fused_block_geometry,
-    halo_block_spec,
-    resident_fits,
+    pad_last2,
+    round_up,
     shift2d,
+    sublanes,
 )
 
 
-def _kernel(x_ref, *refs, spec: StencilSpec, r: int, T: int,
-            block_h: int, H: int, W: int, bc_value: float | None):
-    w_ref, o_ref = (refs[0], refs[1]) if len(refs) == 2 else (None, refs[0])
-    i = pl.program_id(1)
-    xb = x_ref[0].astype(jnp.float32)  # (block_h + 2Tr, Wp + 2Tr)
-    halo = T * r
-    row0 = i * block_h - halo  # global row of xb[0, 0]
-    col0 = -halo
+def _kernel(*refs, spec: StencilSpec, r: int, T: int, bh: int,
+            hb: int | None, halo: int, H: int, W: int,
+            bc_value: float | None, pin_input: bool):
+    """T sweeps of one block: ``refs`` are x's centre block (then, when
+    ``hb`` is set, its two halo-neighbour blocks), the same for the weight
+    fields of a variable spec, and the output block."""
+    refs = list(refs)
+    o_ref = refs.pop()
+    n_x = 1 if hb is None else 3
+    x_refs, w_refs = refs[:n_x], refs[n_x:]
 
-    def coords(shape, ro, co):
-        rows = ro + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-        cols = co + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-        return rows, cols
+    def extend(refs):
+        """The centre block with its ``halo`` neighbour rows attached."""
+        if hb is None:
+            return refs[0][...].astype(jnp.float32)
+        c, top, bot = (ref[...].astype(jnp.float32) for ref in refs)
+        return jnp.concatenate(
+            [top[..., hb - halo:, :], c, bot[..., :halo, :]], axis=-2)
 
-    rows, cols = coords(xb.shape, row0, col0)
-    in_array = (rows >= 0) & (rows < H) & (cols >= 0) & (cols < W)
-    xb = jnp.where(in_array, xb, 0.0)
-    if bc_value is not None:
-        shell = in_array & ~(
-            (rows >= 1) & (rows < H - 1) & (cols >= 1) & (cols < W - 1)
-        )
-        xb = jnp.where(shell, np.float32(bc_value), xb)
+    xb = extend(x_refs)
+    wb = extend(w_refs) if w_refs else None
+    row0 = pl.program_id(1) * bh - halo        # global row of xb[..., 0, :]
+    rows = row0 + jax.lax.broadcasted_iota(jnp.int32, xb.shape[-2:], 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, xb.shape[-2:], 1)
+    in_array = (rows >= 0) & (rows < H)
+    shell = in_array & ~((rows >= 1) & (rows < H - 1)
+                         & (cols >= 1) & (cols < W - 1))
+    bc = None if bc_value is None else np.float32(bc_value)
 
-    for t in range(T):
+    def zones(y, pin):
+        if hb is not None:
+            y = jnp.where(in_array, y, 0.0)
+        if pin:
+            y = jnp.where(shell, bc, y)
+        return y
+
+    def sweep(_, y):
         acc = None
-        # After this iteration the valid window shrinks by r per side: the
-        # output spans rows [row0 + r, ...], i.e. offset (t+1)*r into the
-        # halo-replicated per-cell weight block (which is aligned with the
-        # *initial* xb).  Garbage field reads only land on out-of-array
-        # output cells, which the in_array mask below zeroes.
-        ah, aw = xb.shape[0] - 2 * r, xb.shape[1] - 2 * r
-        o0 = (t + 1) * r
         k = 0
+        yp = pad_last2(y, r, r)
         for off, wgt in spec.taps:
-            term = shift2d(xb, off[0], off[1], r)
+            term = shift2d(yp, off[0], off[1], r)
             if isinstance(wgt, WeightField):
-                term = term * w_ref[k, o0:o0 + ah, o0:o0 + aw].astype(
-                    jnp.float32)
+                term = term * wb[k]
                 k += 1
             else:
                 term = term * np.float32(wgt)
             acc = term if acc is None else acc + term
-        row0 += r
-        col0 += r
-        rows, cols = coords(acc.shape, row0, col0)
-        in_array = (rows >= 0) & (rows < H) & (cols >= 0) & (cols < W)
-        acc = jnp.where(in_array, acc, 0.0)
-        if bc_value is not None:
-            shell = in_array & ~(
-                (rows >= 1) & (rows < H - 1) & (cols >= 1) & (cols < W - 1)
-            )
-            acc = jnp.where(shell, np.float32(bc_value), acc)
-        xb = acc
+        return zones(acc, bc is not None)
 
-    o_ref[0] = xb.astype(o_ref.dtype)
+    xb = zones(xb, bc is not None and pin_input)
+    xb = jax.lax.fori_loop(0, T, sweep, xb)
+    o_ref[...] = xb[..., halo:halo + o_ref.shape[-2], :].astype(o_ref.dtype)
 
 
-def _shift2d_zfill(xb: jnp.ndarray, dr: int, dc: int, r: int) -> jnp.ndarray:
-    """result[i,j] = xb[i+dr, j+dc] with zero fill — same contract as
-    ``shift2d`` but for a block with no halo (the resident strategy)."""
-    h, w = xb.shape
-    xp = jnp.pad(xb, ((r, r), (r, r)))
-    return jax.lax.slice(xp, (r + dr, r + dc), (r + dr + h, r + dc + w))
+def sweep2d(x, spec: StencilSpec, *, T: int, bc_value: float | None,
+            pin_input: bool, block_h: int, rim: str, interpret: bool,
+            fields=None) -> jnp.ndarray:
+    """``T`` stencil sweeps of x: (batch, H, W) in one ``pallas_call``.
 
-
-def _resident_kernel(x_ref, *refs, spec: StencilSpec, r: int, T: int,
-                     H: int, W: int, bc_value: float | None):
-    """T iterations with the whole grid in VMEM; the rim is *refreshed*
-    (out-of-grid zeroed, shell re-pinned) every iteration instead of being
-    carried in a T·r-deep halo, so no work is redundant and T is unbounded.
-    Per-cell weight fields (if any) are output-aligned full-grid blocks.
+    ``pin_input`` pins the input's Dirichlet shell to ``bc_value`` before
+    the first sweep (the fused Jacobi step); without it the shell is read
+    as given (``stencil2d``).  Per-cell weight fields (variable specs) are
+    streamed as an extra operand tiled like ``x``; ``fields`` overrides the
+    spec's baked values with a runtime (V, H, W) stack.
     """
-    w_ref, o_ref = (refs[0], refs[1]) if len(refs) == 2 else (None, refs[0])
-    xb = x_ref[0].astype(jnp.float32)  # (Hp, Wp) — the entire padded grid
-    rows = jax.lax.broadcasted_iota(jnp.int32, xb.shape, 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, xb.shape, 1)
-    in_array = (rows < H) & (cols < W)
-    shell = in_array & ~(
-        (rows >= 1) & (rows < H - 1) & (cols >= 1) & (cols < W - 1)
-    )
-    xb = jnp.where(in_array, xb, 0.0)
-    if bc_value is not None:
-        xb = jnp.where(shell, np.float32(bc_value), xb)
+    B, H, W = x.shape
+    r = spec.radius
+    itemsize = x.dtype.itemsize
+    wf = None
+    if spec.is_variable:
+        if fields is None:
+            fields = np.stack([w.array for _, w in spec.taps
+                               if isinstance(w, WeightField)])
+        wf = jnp.asarray(fields, jnp.float32)
+    planes = 1 if wf is None else 1 + wf.shape[0]
+    bh, halo = fused_block_geometry(H, W, T, r, block_h, rim, itemsize,
+                                    planes=planes)
+    if bh == H:
+        hb, halo = None, 0
+        bb = batch_block(B, H, W, itemsize)
+    else:
+        hb = round_up(halo, sublanes(itemsize))
+        bb = batch_block(B, bh + 2 * hb, W, itemsize)
+    kern = functools.partial(_kernel, spec=spec, r=r, T=T, bh=bh, hb=hb,
+                             halo=halo, H=H, W=W, bc_value=bc_value,
+                             pin_input=pin_input)
 
-    for _ in range(T):
-        acc = None
-        k = 0
-        for off, wgt in spec.taps:
-            term = _shift2d_zfill(xb, off[0], off[1], r)
-            if isinstance(wgt, WeightField):
-                term = term * w_ref[k].astype(jnp.float32)
-                k += 1
-            else:
-                term = term * np.float32(wgt)
-            acc = term if acc is None else acc + term
-        acc = jnp.where(in_array, acc, 0.0)
-        if bc_value is not None:
-            acc = jnp.where(shell, np.float32(bc_value), acc)
-        xb = acc
+    def specs(lead, lead_idx):
+        """Centre block and its two aligned halo neighbours (clamped at the
+        grid edges, where the kernel masks them to zeros anyway)."""
+        out = [pl.BlockSpec((lead, bh, W), lambda b, i: (lead_idx(b), i, 0))]
+        if hb is not None:
+            step, last = bh // hb, pl.cdiv(H, hb) - 1
+            out += [
+                pl.BlockSpec((lead, hb, W), lambda b, i: (
+                    lead_idx(b), jnp.maximum(i * step - 1, 0), 0)),
+                pl.BlockSpec((lead, hb, W), lambda b, i: (
+                    lead_idx(b), jnp.minimum((i + 1) * step, last), 0)),
+            ]
+        return out
 
-    o_ref[0] = xb.astype(o_ref.dtype)
+    in_specs = specs(bb, lambda b: b)
+    if wf is not None:
+        in_specs += specs(wf.shape[0], lambda b: 0)
+    operands = [v for v in (x, wf) if v is not None
+                for _ in range(1 if hb is None else 3)]
+    return pl.pallas_call(
+        kern,
+        grid=(pl.cdiv(B, bb), pl.cdiv(H, bh)),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((bb, bh, W), lambda b, i: (b, i, 0)),
+        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        interpret=interpret,
+    )(*operands)
 
 
 @functools.partial(
@@ -170,86 +183,16 @@ def jacobi2d_fused_step(
 ) -> jnp.ndarray:
     """``fuse`` Jacobi iterations in one kernel pass.  x: (batch, H, W).
 
-    Assumes the Dirichlet shell of x is already set (wrapper does this);
-    with bc_value=None computes ``fuse`` raw zero-padded stencil steps.
-    ``rim`` selects the fusion geometry (see module docstring); the
-    "resident" strategy requires the grid to fit one VMEM block.
-
-    Variable-coefficient specs stream their per-cell weight fields as an
-    extra operand: trapezoid blocks carry the same T·r halo replication as
-    x (iteration t reads the fields at static offset (t+1)·r), the resident
-    strategy reads the full output-aligned grid.  ``fields`` optionally
-    overrides the spec's baked values with a runtime (V, H, W) stack.
+    Pins the Dirichlet shell of x before the first iteration; with
+    bc_value=None computes ``fuse`` raw zero-padded stencil steps.  ``rim``
+    selects the fusion geometry (see module docstring); "resident" requires
+    the grid to fit one VMEM block (the dispatcher checks this with
+    ``tiling.fits_vmem``; the TPU compiler refuses a larger one).  ``fields``
+    optionally overrides a
+    variable spec's baked per-cell values with a runtime (V, H, W) stack.
     """
     if spec.ndim != 2:
         raise ValueError("jacobi2d_fused_step needs a 2D spec")
-    interpret = default_interpret(interpret)
-    B, H, W = x.shape
-    r = spec.radius
-    bh, Hp, Wp, halo = fused_block_geometry(H, W, fuse, r, block_h, rim)
-    xp = jnp.pad(x, ((0, 0), (0, Hp - H), (0, Wp - W)))
-
-    wf = None
-    if spec.is_variable:
-        if fields is None:
-            fields = np.stack([w.array for _, w in spec.taps
-                               if isinstance(w, WeightField)])
-        wf = jnp.asarray(fields, jnp.float32)
-        wf = jnp.pad(wf, ((0, 0), (0, Hp - H), (0, Wp - W)))
-
-    if rim == "resident":
-        if not resident_fits((H, W), np.dtype(np.float32).itemsize):
-            raise ValueError(
-                f"rim='resident' needs the whole {H}x{W} grid in one VMEM "
-                f"block; use rim='trapezoid' for grids this large")
-        kern = functools.partial(
-            _resident_kernel, spec=spec, r=r, T=fuse, H=H, W=W,
-            bc_value=bc_value,
-        )
-        in_specs = [pl.BlockSpec((1, Hp, Wp), lambda b: (b, 0, 0))]
-        operands = [xp]
-        if wf is not None:
-            in_specs.append(
-                pl.BlockSpec((wf.shape[0], Hp, Wp), lambda b: (0, 0, 0)))
-            operands.append(wf)
-        out = pl.pallas_call(
-            kern,
-            grid=(B,),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, Hp, Wp), lambda b: (b, 0, 0)),
-            out_shape=jax.ShapeDtypeStruct((B, Hp, Wp), x.dtype),
-            interpret=interpret,
-        )(*operands)
-        return out[:, :H, :W]
-
-    kern = functools.partial(
-        _kernel, spec=spec, r=r, T=fuse, block_h=bh, H=H, W=W, bc_value=bc_value
-    )
-    in_specs = [
-        halo_block_spec(
-            (1, bh + 2 * halo, Wp + 2 * halo),
-            lambda b, i: (b, i * bh, 0),
-            ((0, 0), (halo, halo), (halo, halo)),
-        )
-    ]
-    operands = [xp]
-    if wf is not None:
-        # Same halo-replicated geometry as x, shared across the batch axis:
-        # in-kernel iteration t slices the fields at offset (t+1)*r.
-        in_specs.append(
-            halo_block_spec(
-                (wf.shape[0], bh + 2 * halo, Wp + 2 * halo),
-                lambda b, i: (0, i * bh, 0),
-                ((0, 0), (halo, halo), (halo, halo)),
-            )
-        )
-        operands.append(wf)
-    out = pl.pallas_call(
-        kern,
-        grid=(B, Hp // bh),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, bh, Wp), lambda b, i: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Hp, Wp), x.dtype),
-        interpret=interpret,
-    )(*operands)
-    return out[:, :H, :W]
+    return sweep2d(x, spec, T=fuse, bc_value=bc_value, pin_input=True,
+                   block_h=block_h, rim=rim,
+                   interpret=default_interpret(interpret), fields=fields)

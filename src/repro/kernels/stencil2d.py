@@ -3,16 +3,17 @@ conv encoding (DESIGN §2).
 
 On the WSE the grid lives in per-core SRAM and neighbour taps arrive over the
 fabric.  The TPU analogue: row-tile the grid into VMEM blocks with a
-radius-r halo (overlapping element-indexed reads via ``tiling.halo_block_spec``),
-apply the taps as *shifted adds* on the VPU, and write back the interior.  A 5-point stencil
-has no MXU-shaped reuse at C=1 — im2col conv would waste 9/5 of its MACs and
-round-trip through a matmul — so the direct form is the roofline-correct
-choice: arithmetic intensity ≈ 7 FLOP / 8 bytes streamed, i.e. memory-bound,
-and the kernel's job is to stream HBM→VMEM exactly once per element.
+radius-r halo, apply the taps as *shifted adds* on the VPU, and write back
+the interior.  A 5-point stencil has no MXU-shaped reuse at C=1 — im2col
+conv would waste 9/5 of its MACs and round-trip through a matmul — so the
+direct form is the roofline-correct choice: arithmetic intensity ≈ 7 FLOP /
+8 bytes streamed, i.e. memory-bound, and the kernel's job is to stream
+HBM→VMEM exactly once per element.
 
-Block geometry: (block_h + 2r, W) input tiles, (block_h, W) output tiles.
-W rides the 128-wide lane dimension (wrapper pads W to a multiple of 128);
-block_h is sublane-aligned (multiple of 8).
+One step is the T=1 case of the fused kernel in ``jacobi_fused.py``, which
+owns the block geometry: whole rows per block (W rides the 128-wide lane
+dimension at its full extent), row halos read from aligned neighbour
+blocks, zero column halos built in VMEM.
 """
 from __future__ import annotations
 
@@ -20,51 +21,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
-from jax.experimental import pallas as pl
 
-from repro.core.stencil import StencilSpec, WeightField
-from repro.kernels.tiling import (default_interpret, halo_block_spec,
-                                  round_up, shift2d)
-
-
-def _stencil_block(xb: jnp.ndarray, spec: StencilSpec, r: int,
-                   w_ref=None) -> jnp.ndarray:
-    """Shifted-add accumulation; varying taps read their per-cell weight
-    block (stacked tap-major, aligned with the output tile) from ``w_ref``."""
-    acc = None
-    k = 0
-    for off, wgt in spec.taps:
-        term = shift2d(xb, off[0], off[1], r).astype(jnp.float32)
-        if isinstance(wgt, WeightField):
-            term = term * w_ref[k].astype(jnp.float32)
-            k += 1
-        else:
-            term = term * np.float32(wgt)
-        acc = term if acc is None else acc + term
-    return acc
-
-
-def _kernel(x_ref, *refs, spec: StencilSpec, r: int, block_h: int,
-            H: int, W: int, bc_value: float | None):
-    w_ref, o_ref = (refs[0], refs[1]) if len(refs) == 2 else (None, refs[0])
-    i = pl.program_id(1)
-    xb = x_ref[0]  # (block_h + 2r, Wp + 2r)
-    bh2, bw2 = xb.shape
-    # Global coordinates of every row/col in the halo block.
-    rows = i * block_h - r + jax.lax.broadcasted_iota(jnp.int32, (bh2, bw2), 0)
-    cols = -r + jax.lax.broadcasted_iota(jnp.int32, (bh2, bw2), 1)
-    # Out-of-array halo reads are undefined — zero them (zero-pad semantics).
-    xb = jnp.where((rows >= 0) & (rows < H) & (cols >= 0) & (cols < W), xb, 0.0)
-    out = _stencil_block(xb, spec, r, w_ref)
-    if bc_value is not None:
-        # Fused paper mask trick: interior keeps the stencil result, the
-        # boundary shell is pinned to the Dirichlet value.
-        orows = rows[r:-r, r:-r] if r else rows
-        ocols = cols[r:-r, r:-r] if r else cols
-        interior = (orows >= 1) & (orows < H - 1) & (ocols >= 1) & (ocols < W - 1)
-        out = jnp.where(interior, out, np.float32(bc_value))
-    o_ref[0] = out.astype(o_ref.dtype)
+from repro.core.stencil import StencilSpec
+from repro.kernels.jacobi_fused import sweep2d
+from repro.kernels.tiling import default_interpret
 
 
 @functools.partial(
@@ -91,43 +51,6 @@ def stencil2d(
     """
     if spec.ndim != 2:
         raise ValueError("stencil2d needs a 2D spec")
-    interpret = default_interpret(interpret)
-    B, H, W = x.shape
-    r = spec.radius
-    bh = min(block_h, round_up(H, 8))
-    Hp = round_up(H, bh)
-    Wp = round_up(W, 128)
-    xp = jnp.pad(x, ((0, 0), (0, Hp - H), (0, Wp - W)))
-
-    kern = functools.partial(
-        _kernel, spec=spec, r=r, block_h=bh, H=H, W=W, bc_value=bc_value
-    )
-    in_specs = [
-        halo_block_spec(
-            (1, bh + 2 * r, Wp + 2 * r),
-            lambda b, i: (b, i * bh, 0),
-            ((0, 0), (r, r), (r, r)),
-        )
-    ]
-    operands = [xp]
-    if spec.is_variable:
-        # Per-cell weight fields stream as a second operand, tiled over the
-        # same row blocks as the *output* (no halo — fields index the output
-        # cell) and shared across the batch grid axis.
-        if fields is None:
-            fields = np.stack([w.array for _, w in spec.taps
-                               if isinstance(w, WeightField)])
-        wf = jnp.asarray(fields, jnp.float32)
-        wf = jnp.pad(wf, ((0, 0), (0, Hp - H), (0, Wp - W)))
-        in_specs.append(
-            pl.BlockSpec((wf.shape[0], bh, Wp), lambda b, i: (0, i, 0)))
-        operands.append(wf)
-    out = pl.pallas_call(
-        kern,
-        grid=(B, Hp // bh),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, bh, Wp), lambda b, i: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Hp, Wp), x.dtype),
-        interpret=interpret,
-    )(*operands)
-    return out[:, :H, :W]
+    return sweep2d(x, spec, T=1, bc_value=bc_value, pin_input=False,
+                   block_h=block_h, rim="trapezoid",
+                   interpret=default_interpret(interpret), fields=fields)

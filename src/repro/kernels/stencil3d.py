@@ -2,9 +2,12 @@
 
 The paper could not express 3D natively (no Conv3D on the CS-1) and paid a
 Z²-banded channel matrix instead (Figures 3-4).  On TPU we tile the X
-dimension into VMEM blocks with halo (``tiling.halo_block_spec``); Z and Y stay whole in
-the block (Z is small in the paper's workloads — Z=10 — and Y rides the
-128-lane dim).  Z-shifts are in-block with zero fill via concatenation.
+dimension into VMEM blocks; Z and Y stay whole in the block (Z is small in
+the paper's workloads — Z=10 — and Y rides the 128-lane dim at its full
+extent, as the TPU compiler's block rule requires).  X halo rows come from
+the two aligned neighbour blocks (none when one block holds all of X), the
+Y halo is zeros built in VMEM, and Z-shifts are in-block with zero fill via
+concatenation.  Small instances are batched several to a block.
 """
 from __future__ import annotations
 
@@ -16,32 +19,46 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.core.stencil import StencilSpec, WeightField
-from repro.kernels.tiling import default_interpret, halo_block_spec, round_up
+from repro.kernels.tiling import (batch_block, default_interpret, pad_last2,
+                                  round_up, row_block, sublanes)
 
 
 def _shift3d(xb: jnp.ndarray, dz: int, dx: int, dy: int, r: int) -> jnp.ndarray:
-    """result[z,i,j] = xb_padded[z+dz, r+i+dx, r+j+dy], zero-filled in Z."""
-    Z, h, w = xb.shape
+    """result[..., z,i,j] = xb[..., z+dz, r+i+dx, r+j+dy], zero-filled in Z."""
+    Z, h, w = xb.shape[-3:]
+    lead = xb.shape[:-3]
     if dz > 0:
-        xz = jnp.concatenate([xb[dz:], jnp.zeros((dz, h, w), xb.dtype)], axis=0)
+        xz = jnp.concatenate(
+            [xb[..., dz:, :, :], jnp.zeros(lead + (dz, h, w), xb.dtype)],
+            axis=-3)
     elif dz < 0:
-        xz = jnp.concatenate([jnp.zeros((-dz, h, w), xb.dtype), xb[:dz]], axis=0)
+        xz = jnp.concatenate(
+            [jnp.zeros(lead + (-dz, h, w), xb.dtype), xb[..., :dz, :, :]],
+            axis=-3)
     else:
         xz = xb
-    return jax.lax.slice(xz, (0, r + dx, r + dy), (Z, h - r + dx, w - r + dy))
+    return xz[..., r + dx:h - r + dx, r + dy:w - r + dy]
 
 
-def _kernel(x_ref, *refs, spec: StencilSpec, r: int, block_x: int,
+def _kernel(*refs, spec: StencilSpec, r: int, bx: int, hb: int | None,
             Z: int, X: int, Y: int, bc_value: float | None):
-    w_ref, o_ref = (refs[0], refs[1]) if len(refs) == 2 else (None, refs[0])
-    i = pl.program_id(1)
-    xb = x_ref[0].astype(jnp.float32)  # (Z, block_x + 2r, Yp + 2r)
-    _, bx2, by2 = xb.shape
-    zs = jax.lax.broadcasted_iota(jnp.int32, xb.shape, 0)
-    xs = i * block_x - r + jax.lax.broadcasted_iota(jnp.int32, xb.shape, 1)
-    ys = -r + jax.lax.broadcasted_iota(jnp.int32, xb.shape, 2)
-    in_array = (xs >= 0) & (xs < X) & (ys >= 0) & (ys < Y)
-    xb = jnp.where(in_array, xb, 0.0)
+    refs = list(refs)
+    o_ref = refs.pop()
+    w_ref = refs.pop() if spec.is_variable else None
+    xb = refs[0][...].astype(jnp.float32)          # (bb, Z, bx, Y)
+    if hb is None:                                 # one block holds all of X
+        x0 = -r
+        xb = pad_last2(xb, r, r)
+    else:
+        x0 = pl.program_id(1) * bx - r
+        top, bot = refs[1][...], refs[2][...]
+        xb = jnp.concatenate([top[..., hb - r:, :].astype(jnp.float32), xb,
+                              bot[..., :r, :].astype(jnp.float32)], axis=-2)
+        xb = pad_last2(xb, 0, r)
+    shape = xb.shape[-2:]
+    xs = x0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    ys = -r + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    xb = jnp.where((xs >= 0) & (xs < X) & (ys >= 0) & (ys < Y), xb, 0.0)
 
     acc = None
     k = 0
@@ -55,16 +72,13 @@ def _kernel(x_ref, *refs, spec: StencilSpec, r: int, block_x: int,
         acc = term if acc is None else acc + term
 
     if bc_value is not None:
-        ozs = zs[:, r:-r, r:-r] if r else zs
-        oxs = xs[:, r:-r, r:-r] if r else xs
-        oys = ys[:, r:-r, r:-r] if r else ys
-        interior = (
-            (ozs >= 1) & (ozs < Z - 1)
-            & (oxs >= 1) & (oxs < X - 1)
-            & (oys >= 1) & (oys < Y - 1)
-        )
+        zs = jax.lax.broadcasted_iota(jnp.int32, acc.shape[-3:], 0)
+        oxs = x0 + r + jax.lax.broadcasted_iota(jnp.int32, acc.shape[-3:], 1)
+        oys = jax.lax.broadcasted_iota(jnp.int32, acc.shape[-3:], 2)
+        interior = ((zs >= 1) & (zs < Z - 1) & (oxs >= 1) & (oxs < X - 1)
+                    & (oys >= 1) & (oys < Y - 1))
         acc = jnp.where(interior, acc, np.float32(bc_value))
-    o_ref[0] = acc.astype(o_ref.dtype)
+    o_ref[...] = acc.astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -89,37 +103,39 @@ def stencil3d(
     interpret = default_interpret(interpret)
     B, Z, X, Y = x.shape
     r = spec.radius
-    bx = min(block_x, round_up(X, 8))
-    Xp = round_up(X, bx)
-    Yp = round_up(Y, 128)
-    xp = jnp.pad(x, ((0, 0), (0, 0), (0, Xp - X), (0, Yp - Y)))
-
-    kern = functools.partial(
-        _kernel, spec=spec, r=r, block_x=bx, Z=Z, X=X, Y=Y, bc_value=bc_value
-    )
-    in_specs = [
-        halo_block_spec(
-            (1, Z, bx + 2 * r, Yp + 2 * r),
-            lambda b, i: (b, 0, i * bx, 0),
-            ((0, 0), (0, 0), (r, r), (r, r)),
-        )
-    ]
-    operands = [xp]
+    itemsize = x.dtype.itemsize
+    # Z whole planes per instance (and per weight field) ride along in
+    # every block.
+    bx = row_block(X, Y, r, block_x, itemsize,
+                   planes=Z * (1 + spec.num_variable_taps))
+    bb = max(1, batch_block(B, bx, Y, itemsize) // Z)
+    hb = None if bx == X else round_up(r, sublanes(itemsize))
+    kern = functools.partial(_kernel, spec=spec, r=r, bx=bx, hb=hb, Z=Z, X=X,
+                             Y=Y, bc_value=bc_value)
+    in_specs = [pl.BlockSpec((bb, Z, bx, Y), lambda b, i: (b, 0, i, 0))]
+    operands = [x]
+    if hb is not None:
+        step, last = bx // hb, pl.cdiv(X, hb) - 1
+        in_specs += [
+            pl.BlockSpec((bb, Z, hb, Y), lambda b, i: (
+                b, 0, jnp.maximum(i * step - 1, 0), 0)),
+            pl.BlockSpec((bb, Z, hb, Y), lambda b, i: (
+                b, 0, jnp.minimum((i + 1) * step, last), 0)),
+        ]
+        operands += [x, x]
     if spec.is_variable:
         # Per-cell weight fields: output-aligned X blocks, batch-shared.
-        fields = np.stack([w.array for _, w in spec.taps
-                           if isinstance(w, WeightField)])
-        wf = jnp.asarray(fields, jnp.float32)
-        wf = jnp.pad(wf, ((0, 0), (0, 0), (0, Xp - X), (0, Yp - Y)))
-        in_specs.append(
-            pl.BlockSpec((wf.shape[0], Z, bx, Yp), lambda b, i: (0, 0, i, 0)))
+        wf = jnp.asarray(np.stack([w.array for _, w in spec.taps
+                                   if isinstance(w, WeightField)]),
+                         jnp.float32)
+        in_specs.append(pl.BlockSpec((wf.shape[0], Z, bx, Y),
+                                     lambda b, i: (0, 0, i, 0)))
         operands.append(wf)
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kern,
-        grid=(B, Xp // bx),
+        grid=(pl.cdiv(B, bb), pl.cdiv(X, bx)),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, Z, bx, Yp), lambda b, i: (b, 0, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Z, Xp, Yp), x.dtype),
+        out_specs=pl.BlockSpec((bb, Z, bx, Y), lambda b, i: (b, 0, i, 0)),
+        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         interpret=interpret,
     )(*operands)
-    return out[:, :, :X, :Y]

@@ -135,11 +135,8 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
         if hasattr(mem, k)
     }
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        # Older JAX returns one dict per computation; newer returns one dict.
-        cost = cost[0] if cost else {}
     record["cost_analysis"] = {
-        k: float(v) for k, v in dict(cost or {}).items()
+        k: float(v) for k, v in cost.items()
         if isinstance(v, (int, float)) and (
             k in ("flops", "bytes accessed", "optimal_seconds")
             or k.startswith("bytes accessed"))

@@ -22,16 +22,21 @@ import jax
 import jax.numpy as jnp
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """shard_map across JAX versions: ``jax.shard_map(check_vma=)`` is the
-    new spelling, ``jax.experimental.shard_map.shard_map(check_rep=)`` the
-    old one (<= 0.4.x)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
+def auto_axes(mesh):
+    """The same devices and axis names with every axis ``Auto``.
+
+    ``jax.make_mesh`` returns ``Explicit`` axes, under which
+    ``with_sharding_constraint`` refuses named specs and a jitted
+    ``shard_map`` wants a mesh context.  The halo runner, the pipeline and
+    the sharding rules place data with named specs inside ``jit``, so they
+    work on this view of whatever mesh the caller built.  Anything that is
+    not a ``Mesh`` (a bare shape, a test double) passes through.
+    """
+    if not isinstance(mesh, jax.sharding.Mesh):
+        return mesh
+    return jax.sharding.Mesh(
+        mesh.devices, mesh.axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(mesh.axis_names))
 
 
 def _shift_perm(n: int, direction: int) -> list[tuple[int, int]]:

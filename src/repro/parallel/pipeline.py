@@ -33,6 +33,8 @@ def gpipe(
     x: (batch, ...) with batch divisible by n_microbatches; params_stacked:
     pytree with leading stage dim S == mesh.shape[stage_axis].
     """
+    from repro.parallel.halo import auto_axes
+    mesh = auto_axes(mesh)
     S = mesh.shape[stage_axis]
     M = n_microbatches
 
@@ -73,8 +75,9 @@ def gpipe(
         if B % M:
             raise ValueError(f"batch {B} not divisible by microbatches {M}")
         x_mb = x.reshape(M, B // M, *x.shape[1:])
-        from repro.parallel.halo import shard_map_compat
-        fn = shard_map_compat(local_fn, mesh, (P(stage_axis), P()), P())
+        fn = jax.shard_map(local_fn, mesh=mesh,
+                           in_specs=(P(stage_axis), P()), out_specs=P(),
+                           check_vma=False)
         out = fn(params_stacked, x_mb)
         return out.reshape(B, *x.shape[1:])
 

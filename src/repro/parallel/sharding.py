@@ -88,6 +88,10 @@ class Sharder:
     # over the data axis instead (ssm head-dim / kv seq).
     state_over_data: bool = False
 
+    def __post_init__(self):
+        from repro.parallel.halo import auto_axes
+        object.__setattr__(self, "mesh", auto_axes(self.mesh))
+
     def _axis_size(self, name: str) -> int:
         return self.mesh.shape[name]
 

@@ -82,7 +82,7 @@ class TestHaloMultiDevice:
         out = run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.parallel.halo import exchange_halo_2d, shard_map_compat
+        from repro.parallel.halo import exchange_halo_2d
 
         mesh = jax.make_mesh((2, 4), ("row", "col"))
         H, W, r = 8, 16, 2
@@ -93,8 +93,9 @@ class TestHaloMultiDevice:
             # re-assemble the halo-augmented tiles for inspection
             return xp[None]
 
-        fn = shard_map_compat(gather_padded, mesh, (P("row", "col"),),
-                              P(None, "row", "col"))
+        fn = jax.shard_map(gather_padded, mesh=mesh,
+                           in_specs=(P("row", "col"),),
+                           out_specs=P(None, "row", "col"), check_vma=False)
         tiles = np.asarray(fn(x))  # (1, 2*(4+2r), 4*(4+2r))
         th, tw = H // 2 + 2 * r, W // 4 + 2 * r
         tiles = tiles[0].reshape(2, th, 4, tw).transpose(0, 2, 1, 3)

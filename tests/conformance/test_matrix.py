@@ -153,7 +153,7 @@ class TestAutoBackend:
                                        device_kind="cpu")
         assert cpu_choice not in ("pallas", "pallas_fused")
         tpu_choice, _ = choose_backend(spec, (64, 64), iters=20,
-                                       device_kind="tpu")
+                                       device_kind="TPU v5 lite")
         assert tpu_choice == "pallas_fused"
 
     def test_auto_1d_falls_back_to_a_legal_backend(self):

@@ -422,9 +422,14 @@ class TestResidentRim:
                                    atol=1e-5)
 
     def test_resident_rejects_oversized_grids(self):
-        from repro.kernels.tiling import resident_fits
-        assert resident_fits((64, 64))
-        assert not resident_fits((4096, 4096))
+        from repro.core.plan import _pallas_fits, device_profile
+        v5e = device_profile("TPU v5 lite")
+        assert _pallas_fits(SPEC, (64, 64), 32, v5e, rim="resident")
+        assert not _pallas_fits(SPEC, (4096, 4096), 32, v5e, rim="resident")
+        with pytest.raises(ValueError, match="scoped VMEM"):
+            make_plan(SPEC, (4096, 4096), backend="pallas_fused", bc=1.0,
+                      iters=32, fuse=32, rim="resident",
+                      device_kind="TPU v5 lite")
 
     def test_unknown_rim_raises(self):
         with pytest.raises(ValueError, match="rim"):

@@ -9,7 +9,7 @@ import os
 
 import pytest
 
-from repro.core import choose_backend, laplace_jacobi
+from repro.core import box, choose_backend, laplace_jacobi
 from repro.core.plan import DEVICE_PROFILES, estimate_seconds
 from repro.core.solver import select_fuse
 
@@ -52,18 +52,37 @@ class TestRooflineModel:
         # On the TPU profile a large 2D Jacobi is HBM-bound: each doubling of
         # the fuse depth halves traffic and must price cheaper.
         spec = laplace_jacobi(2)
-        tpu = DEVICE_PROFILES["tpu"]
+        tpu = DEVICE_PROFILES["TPU v5 lite"]
         ests = [estimate_seconds("pallas_fused", spec, (512, 512), 64, tpu,
                                  fuse=f) for f in (1, 2, 4, 8)]
         assert ests == sorted(ests, reverse=True), ests
 
+    @pytest.mark.parametrize("grid, want", [((512, 512), 8),
+                                            ((4096, 16384), 4)],
+                             ids=["fits", "vmem-bound"])
+    def test_default_fuse_priced_is_fuse_planned(self, grid, want):
+        # fuse=None prices the depth make_plan resolves, which the VMEM
+        # budget lowers on wide rows of a radius-2 star (8 does not fit).
+        from repro.core import make_plan, star
+        spec = star(2, [0.1, 0.1])
+        plan = make_plan(spec, grid, backend="pallas_fused", bc=1.0,
+                         iters=16, device_kind="TPU v5 lite",
+                         interpret=True, tuned=None)
+        assert plan.fuse == want
+        tpu = DEVICE_PROFILES["TPU v5 lite"]
+        assert estimate_seconds("pallas_fused", spec, grid, 16, tpu) == \
+            estimate_seconds("pallas_fused", spec, grid, 16, tpu, fuse=want)
+
     def test_fuse_pricing_includes_rim_recompute(self):
-        # Deeper fusion is NOT free: compute time must grow with depth even
-        # as memory time shrinks (the trapezoid redundancy factor).
+        # Deeper fusion is NOT free on a grid that tiles into row blocks:
+        # compute time must grow with depth even as memory time shrinks
+        # (the trapezoid redundancy factor).  A grid that fits one block
+        # runs resident and recomputes nothing at any depth.
         from repro.kernels.tiling import fuse_redundancy
-        r1 = fuse_redundancy((64, 64), 1, 1)
-        r8 = fuse_redundancy((64, 64), 8, 1)
+        r1 = fuse_redundancy((4096, 4096), 1, 1)
+        r8 = fuse_redundancy((4096, 4096), 8, 1)
         assert 1.0 <= r1 < r8
+        assert fuse_redundancy((64, 64), 8, 1) == 1.0
 
     def test_halo_comm_term_scales_with_perimeter(self):
         # The halo communication term is O(perimeter), not O(area): doubling
@@ -116,13 +135,55 @@ class TestRooflineModel:
         spec = laplace_jacobi(2)
         # memory-bound TPU cell: fusion wins until rim recompute crosses the
         # HBM saving (the model finds the crossover, not the deepest depth)
-        assert select_fuse("pallas_fused", spec, (512, 512), 16, "tpu") > 1
-        # compute-bound CPU cell: fusing only adds rim recompute
-        assert select_fuse("pallas", spec, (16, 16), 16, "cpu") == 1
+        assert select_fuse("pallas_fused", spec, (512, 512), 16,
+                           "TPU v5 lite") > 1
+        # compute-bound CPU cell (9-point box on a grid that tiles into row
+        # blocks): fusing only adds rim recompute
+        assert select_fuse("pallas", box(2), (4096, 4096), 16, "cpu") == 1
         # non-fusing backends and 3D kernels never fuse
         assert select_fuse("conv", spec, (64, 64), 16, "cpu") is None
         assert select_fuse("pallas", laplace_jacobi(3), (8, 16, 16), 16,
-                           "tpu") is None
+                           "TPU v5 lite") is None
+
+
+class TestDeviceIdentity:
+    """Profiles and tuned-table lookups key on ``jax.Device.device_kind``;
+    a device with no profile is an error, never the CPU's rates."""
+
+    def test_unknown_device_kind_raises(self):
+        from repro.core.plan import device_profile
+        spec = laplace_jacobi(2)
+        with pytest.raises(ValueError, match="no device profile"):
+            device_profile("TPU v99")
+        with pytest.raises(ValueError, match="no device profile"):
+            choose_backend(spec, TABLE1_GRID, iters=8, device_kind="tpu")
+        with pytest.raises(ValueError, match="no device profile"):
+            select_fuse("pallas_fused", spec, TABLE1_GRID, 16, "gpu")
+
+    def test_current_device_is_read_from_jax(self):
+        import jax
+        from repro.core.plan import current_device_kind, device_profile
+        assert current_device_kind() == jax.devices()[0].device_kind
+        assert device_profile().kind == current_device_kind()
+
+    def test_pallas_refused_where_its_block_overflows_vmem(self):
+        # A geometry the TPU compiler would refuse is refused up front, with
+        # a reason, and auto never picks it.
+        from repro.core import backend_support
+        spec = laplace_jacobi(2)
+        wide = (64, 1 << 17)
+        for b in ("pallas", "pallas_fused"):
+            sup = backend_support(b, spec, grid_shape=wide)
+            assert not sup and "VMEM" in sup.reason, sup
+        name, _ = choose_backend(spec, wide, iters=8,
+                                 device_kind="TPU v5 lite")
+        assert name not in ("pallas", "pallas_fused")
+        assert backend_support("pallas", spec, grid_shape=(8192, 8192))
+
+    def test_v5e_peaks_are_the_published_ones(self):
+        v5e = DEVICE_PROFILES["TPU v5 lite"]
+        assert v5e.matmul_flops == 197e12 and v5e.mem_bw == 819e9
+        assert v5e.pallas_native
 
 
 class TestRecordedTimings:

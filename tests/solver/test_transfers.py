@@ -66,8 +66,13 @@ def _check_transpose_pairing(fine_shape, rng):
     cshape = coarse_shape(fine_shape)
     x = rng.standard_normal(fine_shape).astype(np.float32)
     e = rng.standard_normal(cshape).astype(np.float32)
-    lhs = float(jnp.sum(_prolong(e, fine_shape) * x))
-    rhs = (2.0 ** nd) * float(jnp.sum(jnp.asarray(e) * _restrict(x)))
+    # The inner products are taken in float64: the property is about the
+    # operators, and an fp32 sum of ~1000 O(1) products that cancel to O(1)
+    # carries its own rounding of that order.
+    f64 = np.float64
+    lhs = float(np.sum(np.asarray(_prolong(e, fine_shape), f64) * x))
+    rhs = (2.0 ** nd) * float(np.sum(e.astype(f64)
+                                     * np.asarray(_restrict(x), f64)))
     scale = max(abs(lhs), abs(rhs), 1.0)
     assert abs(lhs - rhs) / scale < 1e-5, (fine_shape, lhs, rhs)
 

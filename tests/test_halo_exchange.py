@@ -92,7 +92,7 @@ class TestExchange1D:
         out = run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.parallel.halo import exchange_1d, shard_map_compat
+        from repro.parallel.halo import exchange_1d
 
         n, loc, r = 4, 4, 2
         mesh = jax.make_mesh((n,), ("x",))
@@ -103,8 +103,9 @@ class TestExchange1D:
             assert lo.shape == hi.shape == (r,)
             return jnp.concatenate([lo, hi])
 
-        halos = np.asarray(shard_map_compat(
-            f, mesh, (P("x"),), P("x"))(g)).reshape(n, 2 * r)
+        halos = np.asarray(jax.shard_map(
+            f, mesh=mesh, in_specs=(P("x"),), out_specs=P("x"),
+            check_vma=False)(g)).reshape(n, 2 * r)
         gp = np.pad(np.asarray(g), r)  # zero-padded global line
         for i in range(n):
             np.testing.assert_array_equal(halos[i, :r],
@@ -121,15 +122,16 @@ class TestExchange1D:
         out = run_with_devices("""
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from repro.parallel.halo import exchange_1d, shard_map_compat
+        from repro.parallel.halo import exchange_1d
 
         n, loc = 4, 4
         mesh = jax.make_mesh((n,), ("x",))
         g = jnp.zeros((n * loc,), jnp.float32)
         try:
-            shard_map_compat(
+            jax.shard_map(
                 lambda xl: exchange_1d(xl, "x", n, 0, loc + 1)[0],
-                mesh, (P("x"),), P("x"))(g)
+                mesh=mesh, in_specs=(P("x"),), out_specs=P("x"),
+                check_vma=False)(g)
         except ValueError as e:
             assert "exceeds the local extent" in str(e), e
             print("depth-guard ok")
@@ -148,7 +150,7 @@ class TestExchange2D:
         out = run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.parallel.halo import exchange_halo_2d, shard_map_compat
+        from repro.parallel.halo import exchange_halo_2d
 
         nr, nc = 2, 4
         H, W = 8, 16
@@ -167,8 +169,9 @@ class TestExchange2D:
                     gp, (ri * hl, ci * wl), (hl + 2 * r, wl + 2 * r))
                 return jnp.all(aug == want)[None, None]
 
-            ok = shard_map_compat(f, mesh, (P("row", "col"),),
-                                  P("row", "col"))(g)
+            ok = jax.shard_map(f, mesh=mesh, in_specs=(P("row", "col"),),
+                               out_specs=P("row", "col"),
+                               check_vma=False)(g)
             assert np.asarray(ok).all(), f"r={r}"
         print("corner ok")
         """)

@@ -136,3 +136,29 @@ class TestFTRuntime:
         _, stats = run_training(train_step, lambda: {"w": jnp.zeros(1)},
                                 lambda s: jnp.zeros(1), 10, ft)
         assert any(s.is_straggler for s in stats)
+
+
+class TestCompileCache:
+    """JAX's persistent compile cache has one fixed home: the environment's
+    ``JAX_COMPILATION_CACHE_DIR``, else ``<repo>/.jax_cache``."""
+
+    def test_env_var_wins(self, monkeypatch, tmp_path):
+        from repro.compile_cache import compile_cache_dir
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compile_cache_dir() == str(tmp_path)
+
+    def test_fixed_path_inside_the_checkout(self, monkeypatch):
+        from repro.compile_cache import compile_cache_dir
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert compile_cache_dir() == os.path.join(repo, ".jax_cache")
+        # the same path every time: nothing derived from a pid or a clock
+        assert compile_cache_dir() == compile_cache_dir()
+
+    def test_enable_sets_nothing_when_the_env_names_it(self, monkeypatch,
+                                                       tmp_path):
+        from repro.compile_cache import enable_compile_cache
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        before = jax.config.jax_compilation_cache_dir
+        assert enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
