@@ -845,7 +845,9 @@ def _build_fn(spec, grid_shape, backend, bc, mode, iters, fuse, dtype, mesh,
                 def body(t, _):
                     return stencil3d(t, spec, interpret=interpret,
                                      **kw3d), None
-                y, _ = jax.lax.scan(body, x.astype(dtype), None, length=iters)
+                with jax.named_scope("repro.sweep"):
+                    y, _ = jax.lax.scan(body, x.astype(dtype), None,
+                                        length=iters)
                 return y
             return run_raw3d, frozenset()
 
@@ -863,7 +865,9 @@ def _build_fn(spec, grid_shape, backend, bc, mode, iters, fuse, dtype, mesh,
                 def body(t, _):
                     return stencil2d(t, spec, interpret=interpret,
                                      fields=fields, **kw2d), None
-                y, _ = jax.lax.scan(body, x.astype(dtype), None, length=iters)
+                with jax.named_scope("repro.sweep"):
+                    y, _ = jax.lax.scan(body, x.astype(dtype), None,
+                                        length=iters)
                 return y
             return run_raw2d_var, var_ops
         from repro.kernels import jacobi2d_fused_step
@@ -873,8 +877,9 @@ def _build_fn(spec, grid_shape, backend, bc, mode, iters, fuse, dtype, mesh,
                 return jacobi2d_fused_step(t, spec, fuse=fuse,
                                            interpret=interpret, rim=rim,
                                            **kw2d), None
-            y, _ = jax.lax.scan(body, x.astype(dtype), None,
-                                length=iters // fuse)
+            with jax.named_scope("repro.sweep"):
+                y, _ = jax.lax.scan(body, x.astype(dtype), None,
+                                    length=iters // fuse)
             return y
         return run_raw2d, frozenset()
 

@@ -38,11 +38,13 @@ HBM-traffic saving against its trapezoid rim recompute).
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.boundary import BoundaryMode, DirichletBC
 from repro.core.plan import (
@@ -79,7 +81,6 @@ class SolveResult:
       backend/fuse/check_every: what actually ran.
       wall_seconds: wall time of the solve call (includes compilation on the
         first call through a given Solver).
-      est_seconds: the roofline model's estimate for the iterations run.
       costs: per-backend cost table when ``backend="auto"`` chose.
     """
 
@@ -92,7 +93,6 @@ class SolveResult:
     fuse: int
     check_every: int
     wall_seconds: float
-    est_seconds: float
     costs: dict[str, float]
 
 
@@ -214,7 +214,6 @@ class Solver:
                 "fixed-iteration mode")
         self.max_iters = int(max_iters)
         self.dtype = dtype
-        self.device_kind = device_kind
 
         if self.fixed:
             # One chunk of exactly max_iters iterations; no residual pass.
@@ -278,9 +277,9 @@ class Solver:
                                 and entry.backend == backend else "roofline")
         self.backend = self.plan.backend
         self.fuse = self.plan.fuse
-        self.mesh_shape = _mesh_tiling(mesh) if mesh is not None else None
         if not self.fixed:
             self._loop = jax.jit(self._build_loop())
+        self._solve_ids = itertools.count()   # tags each solve's spans
 
     # -- the compiled while_loop ------------------------------------------
 
@@ -315,14 +314,15 @@ class Solver:
             def body(s):
                 k, x, active, res, iters, hist = s
                 y = plan(x, fields=fields, source=source, bc_value=bc_value)
-                err = grid_norm(y - x, axes)
-                done = err <= atol + rtol * grid_norm(y, axes)
-                keep = active.reshape(active.shape + (1,) * (x.ndim - 1))
-                x = jnp.where(keep, y, x)           # frozen instances hold
-                res = jnp.where(active, err, res)
-                hist = hist.at[k].set(jnp.where(active, err, jnp.nan))
-                iters = iters + jnp.where(active, check_every, 0)
-                active = active & ~done
+                with jax.named_scope("repro.check"):
+                    err = grid_norm(y - x, axes)
+                    done = err <= atol + rtol * grid_norm(y, axes)
+                    keep = active.reshape(active.shape + (1,) * (x.ndim - 1))
+                    x = jnp.where(keep, y, x)       # frozen instances hold
+                    res = jnp.where(active, err, res)
+                    hist = hist.at[k].set(jnp.where(active, err, jnp.nan))
+                    iters = iters + jnp.where(active, check_every, 0)
+                    active = active & ~done
                 return (k + 1, x, active, res, iters, hist)
 
             return jax.lax.while_loop(cond, body, state)
@@ -375,44 +375,45 @@ class Solver:
                 f"solver built for grid {self.grid_shape}, got {x0.shape[1:]}")
         b = x0.shape[0]
 
+        # Host spans of one solve, tagged with its id: the dispatch, the wait
+        # for the device, and the host's reads after it.
+        sid = next(self._solve_ids)
         t0 = time.perf_counter()
-        if self.fixed:
-            x = self.plan(x0, fields=fields, source=source, bc_value=bc_value)
+        with TraceAnnotation("repro.solve.dispatch", solve=sid):
+            if self.fixed:
+                x = self.plan(x0, fields=fields, source=source,
+                              bc_value=bc_value)
+            else:
+                k, x, active, res, iters, hist = self._loop(
+                    x0, fields, source, bc_value)
+        with TraceAnnotation("repro.solve.wait", solve=sid):
             jax.block_until_ready(x)
-            wall = time.perf_counter() - t0
-            iterations = np.full((b,), self.max_iters, np.int64)
-            converged = np.zeros((b,), bool)
-            residual = np.full((b,), np.nan, np.float32)
-            history = np.empty((0, b), np.float32)
-        else:
-            k, x, active, res, iters, hist = self._loop(
-                x0, fields, source, bc_value)
-            jax.block_until_ready(x)
-            wall = time.perf_counter() - t0
-            iterations = np.asarray(iters, np.int64)
-            converged = ~np.asarray(active)
-            residual = np.asarray(res)
-            history = np.asarray(hist)[: int(k)]
-
-        est = estimate_seconds(
-            self.backend, self.spec, self.grid_shape,
-            max(int(iterations.max()), 1), device_profile(self.device_kind),
-            fuse=self.fuse,
-            mesh_shape=self.mesh_shape)
-
-        if squeeze:
+        wall = time.perf_counter() - t0
+        with TraceAnnotation("repro.solve.readback", solve=sid):
+            if self.fixed:
+                iterations = np.full((b,), self.max_iters, np.int64)
+                converged = np.zeros((b,), bool)
+                residual = np.full((b,), np.nan, np.float32)
+                history = np.empty((0, b), np.float32)
+            else:
+                iterations = np.asarray(iters, np.int64)
+                converged = ~np.asarray(active)
+                residual = np.asarray(res)
+                history = np.asarray(hist)[: int(k)]
+            if squeeze:
+                return SolveResult(
+                    x=x[0], iterations=int(iterations[0]),
+                    converged=bool(converged[0]),
+                    residual=float(residual[0]),
+                    residual_history=history[:, 0], backend=self.backend,
+                    fuse=self.fuse, check_every=self.check_every,
+                    wall_seconds=wall, costs=self.costs)
             return SolveResult(
-                x=x[0], iterations=int(iterations[0]),
-                converged=bool(converged[0]), residual=float(residual[0]),
-                residual_history=history[:, 0], backend=self.backend,
-                fuse=self.fuse, check_every=self.check_every,
-                wall_seconds=wall, est_seconds=est, costs=self.costs)
-        return SolveResult(
-            x=x, iterations=iterations, converged=converged,
-            residual=residual, residual_history=history,
-            backend=self.backend, fuse=self.fuse,
-            check_every=self.check_every, wall_seconds=wall,
-            est_seconds=est, costs=self.costs)
+                x=x, iterations=iterations, converged=converged,
+                residual=residual, residual_history=history,
+                backend=self.backend, fuse=self.fuse,
+                check_every=self.check_every, wall_seconds=wall,
+                costs=self.costs)
 
     __call__ = solve
 

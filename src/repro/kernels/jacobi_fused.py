@@ -105,8 +105,9 @@ def _kernel(*refs, spec: StencilSpec, r: int, T: int, bh: int,
 
 def sweep2d(x, spec: StencilSpec, *, T: int, bc_value: float | None,
             pin_input: bool, block_h: int, rim: str, interpret: bool,
-            fields=None) -> jnp.ndarray:
-    """``T`` stencil sweeps of x: (batch, H, W) in one ``pallas_call``.
+            name: str, fields=None) -> jnp.ndarray:
+    """``T`` stencil sweeps of x: (batch, H, W) in one ``pallas_call``
+    named ``name`` (the caller's), which a device trace shows.
 
     ``pin_input`` pins the input's Dirichlet shell to ``bc_value`` before
     the first sweep (the fused Jacobi step); without it the shell is read
@@ -162,6 +163,7 @@ def sweep2d(x, spec: StencilSpec, *, T: int, bc_value: float | None,
         out_specs=pl.BlockSpec((bb, bh, W), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         interpret=interpret,
+        name=name,
     )(*operands)
 
 
@@ -195,4 +197,5 @@ def jacobi2d_fused_step(
         raise ValueError("jacobi2d_fused_step needs a 2D spec")
     return sweep2d(x, spec, T=fuse, bc_value=bc_value, pin_input=True,
                    block_h=block_h, rim=rim,
-                   interpret=default_interpret(interpret), fields=fields)
+                   interpret=default_interpret(interpret),
+                   name="jacobi2d_fused_step", fields=fields)

@@ -6,6 +6,10 @@ the stencil DSL drivers) calls.  Each wrapper:
   * scans the kernel over iteration chunks (``fuse`` iterations per pass for
     the temporally-blocked 2D path),
   * auto-selects interpret mode on CPU (TPU runs compiled Mosaic).
+
+The shell pin and the kernel scan run under ``jax.named_scope``s
+(``repro.boundary``, ``repro.sweep``), which name their ops in the HLO
+metadata a device trace carries; the compiled program is unchanged.
 """
 from __future__ import annotations
 
@@ -53,7 +57,8 @@ def jacobi2d(
     if iterations % fuse:
         raise ValueError(f"iterations={iterations} not divisible by fuse={fuse}")
     bc = DirichletBC(bc_value)
-    x = jax.vmap(bc.set_boundary)(x0)
+    with jax.named_scope("repro.boundary"):
+        x = jax.vmap(bc.set_boundary)(x0)
 
     if spec.is_variable and fuse == 1:
         def body(x, _):
@@ -68,7 +73,8 @@ def jacobi2d(
             )
             return y, None
 
-    x, _ = jax.lax.scan(body, x, None, length=iterations // fuse)
+    with jax.named_scope("repro.sweep"):
+        x, _ = jax.lax.scan(body, x, None, length=iterations // fuse)
     return x
 
 
@@ -87,14 +93,16 @@ def jacobi3d(
 ) -> jnp.ndarray:
     """``iterations`` 3D Jacobi steps on (batch, Z, X, Y)."""
     bc = DirichletBC(bc_value)
-    x = jax.vmap(bc.set_boundary)(x0)
+    with jax.named_scope("repro.boundary"):
+        x = jax.vmap(bc.set_boundary)(x0)
 
     def body(x, _):
         y = stencil3d(x, spec, block_x=block_x, bc_value=bc_value,
                       interpret=interpret)
         return y, None
 
-    x, _ = jax.lax.scan(body, x, None, length=iterations)
+    with jax.named_scope("repro.sweep"):
+        x, _ = jax.lax.scan(body, x, None, length=iterations)
     return x
 
 

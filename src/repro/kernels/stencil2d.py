@@ -53,4 +53,5 @@ def stencil2d(
         raise ValueError("stencil2d needs a 2D spec")
     return sweep2d(x, spec, T=1, bc_value=bc_value, pin_input=False,
                    block_h=block_h, rim="trapezoid",
-                   interpret=default_interpret(interpret), fields=fields)
+                   interpret=default_interpret(interpret), name="stencil2d",
+                   fields=fields)

@@ -138,4 +138,5 @@ def stencil3d(
         out_specs=pl.BlockSpec((bb, Z, bx, Y), lambda b, i: (b, 0, i, 0)),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         interpret=interpret,
+        name="stencil3d",
     )(*operands)

@@ -332,6 +332,6 @@ class ServingEngine:
                 residual_history=res.residual_history[:, i],
                 backend=res.backend, fuse=res.fuse,
                 check_every=res.check_every, wall_seconds=res.wall_seconds,
-                est_seconds=res.est_seconds, costs=res.costs)
+                costs=res.costs)
             for i in range(len(group))
         ]
