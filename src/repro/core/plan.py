@@ -828,6 +828,7 @@ def _build_fn(spec, grid_shape, backend, bc, mode, iters, fuse, dtype, mesh,
                 frozenset(("source", "bc_value")))
 
     if backend in ("pallas", "pallas_fused"):
+        from repro.kernels.ops import sweep_scan
         bc_value_s = _scalar_bc_value(bc)
         rim = rim or "trapezoid"
         kw2d = {"block_h": block_h} if block_h else {}
@@ -842,13 +843,9 @@ def _build_fn(spec, grid_shape, backend, bc, mode, iters, fuse, dtype, mesh,
                         frozenset())
 
             def run_raw3d(x, fields, source, bc_value):
-                def body(t, _):
-                    return stencil3d(t, spec, interpret=interpret,
-                                     **kw3d), None
-                with jax.named_scope("repro.sweep"):
-                    y, _ = jax.lax.scan(body, x.astype(dtype), None,
-                                        length=iters)
-                return y
+                return sweep_scan(
+                    lambda t: stencil3d(t, spec, interpret=interpret, **kw3d),
+                    x.astype(dtype), iters)
             return run_raw3d, frozenset()
 
         if bc_value_s is not None:
@@ -862,25 +859,19 @@ def _build_fn(spec, grid_shape, backend, bc, mode, iters, fuse, dtype, mesh,
             from repro.kernels import stencil2d
 
             def run_raw2d_var(x, fields, source, bc_value):
-                def body(t, _):
-                    return stencil2d(t, spec, interpret=interpret,
-                                     fields=fields, **kw2d), None
-                with jax.named_scope("repro.sweep"):
-                    y, _ = jax.lax.scan(body, x.astype(dtype), None,
-                                        length=iters)
-                return y
+                return sweep_scan(
+                    lambda t: stencil2d(t, spec, interpret=interpret,
+                                        fields=fields, **kw2d),
+                    x.astype(dtype), iters)
             return run_raw2d_var, var_ops
         from repro.kernels import jacobi2d_fused_step
 
         def run_raw2d(x, fields, source, bc_value):
-            def body(t, _):
-                return jacobi2d_fused_step(t, spec, fuse=fuse,
-                                           interpret=interpret, rim=rim,
-                                           **kw2d), None
-            with jax.named_scope("repro.sweep"):
-                y, _ = jax.lax.scan(body, x.astype(dtype), None,
-                                    length=iters // fuse)
-            return y
+            return sweep_scan(
+                lambda t: jacobi2d_fused_step(t, spec, fuse=fuse,
+                                              interpret=interpret, rim=rim,
+                                              **kw2d),
+                x.astype(dtype), iters // fuse)
         return run_raw2d, frozenset()
 
     if backend == "halo":

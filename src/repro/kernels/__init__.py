@@ -13,6 +13,7 @@ from repro.kernels.ops import (
     jacobi3d,
     stencil2d,
     stencil3d,
+    sweep_scan,
 )
 
 __all__ = [
@@ -23,4 +24,5 @@ __all__ = [
     "jacobi3d",
     "stencil2d",
     "stencil3d",
+    "sweep_scan",
 ]

@@ -4,7 +4,7 @@ These are the entry points the rest of the framework (examples, benchmarks,
 the stencil DSL drivers) calls.  Each wrapper:
   * sets the Dirichlet shell before iterating,
   * scans the kernel over iteration chunks (``fuse`` iterations per pass for
-    the temporally-blocked 2D path),
+    the temporally-blocked 2D path) with ``sweep_scan``,
   * auto-selects interpret mode on CPU (TPU runs compiled Mosaic).
 
 The shell pin and the kernel scan run under ``jax.named_scope``s
@@ -24,6 +24,23 @@ from repro.kernels.dense_stencil import dense_stencil_matmul
 from repro.kernels.jacobi_fused import jacobi2d_fused_step
 from repro.kernels.stencil2d import stencil2d
 from repro.kernels.stencil3d import stencil3d
+
+
+def sweep_scan(step, x: jnp.ndarray, n: int) -> jnp.ndarray:
+    """``step`` applied ``n`` times to ``x``, as a scan of kernel calls.
+
+    A ``while`` loop's carry and its body's result share one buffer, and a
+    Pallas call cannot write the buffer it reads (its blocks read their
+    neighbours' halos), so a scan of one call per iteration makes XLA copy
+    the whole grid out of the carry before every call.  Two calls to an
+    iteration ping-pong between the carry and a second buffer instead, and
+    no copy is inserted; an odd count runs its last call after the loop.
+    A length of 1 stays one call.  The calls and their order are unchanged.
+    """
+    with jax.named_scope("repro.sweep"):
+        y, _ = jax.lax.scan(lambda t, _: (step(t), None), x, None,
+                            length=n, unroll=2 if n > 1 else 1)
+    return y
 
 
 @functools.partial(
@@ -61,21 +78,17 @@ def jacobi2d(
         x = jax.vmap(bc.set_boundary)(x0)
 
     if spec.is_variable and fuse == 1:
-        def body(x, _):
-            y = stencil2d(x, spec, block_h=block_h, bc_value=bc_value,
-                          interpret=interpret, fields=fields)
-            return y, None
+        def step(x):
+            return stencil2d(x, spec, block_h=block_h, bc_value=bc_value,
+                             interpret=interpret, fields=fields)
     else:
-        def body(x, _):
-            y = jacobi2d_fused_step(
+        def step(x):
+            return jacobi2d_fused_step(
                 x, spec, fuse=fuse, block_h=block_h, bc_value=bc_value,
                 interpret=interpret, rim=rim, fields=fields,
             )
-            return y, None
 
-    with jax.named_scope("repro.sweep"):
-        x, _ = jax.lax.scan(body, x, None, length=iterations // fuse)
-    return x
+    return sweep_scan(step, x, iterations // fuse)
 
 
 @functools.partial(
@@ -96,14 +109,11 @@ def jacobi3d(
     with jax.named_scope("repro.boundary"):
         x = jax.vmap(bc.set_boundary)(x0)
 
-    def body(x, _):
-        y = stencil3d(x, spec, block_x=block_x, bc_value=bc_value,
-                      interpret=interpret)
-        return y, None
+    def step(x):
+        return stencil3d(x, spec, block_x=block_x, bc_value=bc_value,
+                         interpret=interpret)
 
-    with jax.named_scope("repro.sweep"):
-        x, _ = jax.lax.scan(body, x, None, length=iterations)
-    return x
+    return sweep_scan(step, x, iterations)
 
 
 @functools.partial(
@@ -127,14 +137,12 @@ def dense_jacobi_kernel(
     """
     batch = x0.shape[0]
     grid_shape = x0.shape[1:]
-    x = x0.reshape(batch, -1)
 
-    def body(x, _):
-        y = dense_stencil_matmul(x, matrix, bm=bm, bk=bk, bn=bn,
-                                 interpret=interpret)
-        return y, None
+    def step(x):
+        return dense_stencil_matmul(x, matrix, bm=bm, bk=bk, bn=bn,
+                                    interpret=interpret)
 
-    x, _ = jax.lax.scan(body, x, None, length=iterations)
+    x = sweep_scan(step, x0.reshape(batch, -1), iterations)
     return x.reshape(batch, *grid_shape)
 
 
@@ -146,4 +154,5 @@ __all__ = [
     "stencil2d",
     "stencil3d",
     "jacobi2d_fused_step",
+    "sweep_scan",
 ]

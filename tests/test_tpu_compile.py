@@ -157,6 +157,42 @@ def test_table1_converging_loop(one_chip, no_compile_cache):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _while_bodies(text):
+    """The text of each computation of ``text`` that a ``while`` runs."""
+    comps = dict(re.findall(r"^(?:ENTRY )?%([\w.-]+) [^\n]*\{\n(.*?)\n\}$",
+                            text, re.M | re.S))
+    return [comps[b] for b in re.findall(r" while\(.*body=%([\w.-]+)", text)]
+
+
+@pytest.mark.parametrize("ndim, shape, iters, calls", [
+    (2, TABLE1, 100, 2), (3, FIG6, 100, 2), (2, (1024, 64, 64), None, 1),
+], ids=["table1", "fig6", "table1-converge"])
+def test_sweep_loop_copies_no_grid(ndim, shape, iters, calls, one_chip,
+                                   no_compile_cache):
+    # A Pallas call cannot write the buffer it reads, so a loop whose body
+    # is one such call makes XLA copy the grid out of the loop's carry
+    # before every call.  The fixed-sweep scans (25 fuse-4 passes, 100
+    # sweeps) pair their calls and need no copy; the converging loop runs
+    # one fuse-16 call a body, whose output the boundary pin rewrites.
+    solver = Solver(laplace_jacobi(ndim), shape[1:], bc=1.0,
+                    rtol=None if iters else 1e-5, atol=None,
+                    max_iters=iters or 20_000, device_kind=V5E,
+                    interpret=False)
+    program = solver.plan._fn if iters else solver._loop
+    x = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    compiled = program.lower(x, None, None, None).compile()
+    bodies = _while_bodies(compiled.as_text())
+    grid_copy = re.compile(
+        re.escape(f"f32[{','.join(map(str, shape))}]") + r"\{[^}]*\} copy\(")
+    assert bodies
+    for body in bodies:
+        assert not grid_copy.search(body)
+        assert body.count('custom_call_target="tpu_custom_call"') == calls
+    # the loop's two buffers, each lane-padded to 128, as before the pairing
+    padded = np.prod(shape[:-1]) * 128 * 4
+    assert compiled.memory_analysis().temp_size_in_bytes <= 2 * padded + 2**20
+
+
 def test_halo_on_four_chips(topo, no_compile_cache):
     # The mesh jax.make_mesh returns (Explicit axes) must be accepted, and
     # the program must hold no grid-sized constant.
