@@ -534,6 +534,12 @@ class StencilPlan:
     source: str = "explicit"
     rim: str | None = None
     operands: frozenset = frozenset()
+    # The 2D Pallas kernel's row blocks (``tiling.fused_block_geometry``):
+    # rows per block and the halo rows each block reads from each of its
+    # neighbours.  A grid that is one block reads none (``halo_rows`` 0);
+    # None for every other path.
+    block_rows: int | None = None
+    halo_rows: int | None = None
 
     def __call__(self, x: jnp.ndarray, *, fields=None, source=None,
                  bc_value=None) -> jnp.ndarray:
@@ -723,9 +729,14 @@ def make_plan(
                 f"grid need more than the scoped VMEM a {device.kind} "
                 f"kernel gets; use a shallower fuse")
 
-    from repro.kernels.tiling import default_interpret
+    from repro.kernels.tiling import default_interpret, fused_block_geometry
     interpreted = backend in ("pallas", "pallas_fused") \
         and default_interpret(interpret)
+    block_rows = halo_rows = None
+    if backend in ("pallas", "pallas_fused") and spec.ndim == 2:
+        block_rows, halo_rows = fused_block_geometry(
+            *grid_shape, fuse, spec.radius, block_h or 256,
+            rim or "trapezoid", itemsize, planes=1 + spec.num_variable_taps)
 
     fn, operands = _build_fn(spec, grid_shape, backend, bc, mode, iters, fuse,
                              dtype, mesh, interpret, block_h, rim)
@@ -738,7 +749,8 @@ def make_plan(
     return StencilPlan(spec=spec, backend=backend, grid_shape=grid_shape,
                        mode=mode, iters=iters, fuse=fuse, costs=costs, _fn=fn,
                        interpreted=interpreted, source=source, rim=rim,
-                       operands=operands)
+                       operands=operands, block_rows=block_rows,
+                       halo_rows=halo_rows)
 
 
 def _build_fn(spec, grid_shape, backend, bc, mode, iters, fuse, dtype, mesh,

@@ -128,7 +128,7 @@ def sweep2d(x, spec: StencilSpec, *, T: int, bc_value: float | None,
     bh, halo = fused_block_geometry(H, W, T, r, block_h, rim, itemsize,
                                     planes=planes)
     if bh == H:
-        hb, halo = None, 0
+        hb = None
         bb = batch_block(B, H, W, itemsize)
     else:
         hb = round_up(halo, sublanes(itemsize))

@@ -16,20 +16,27 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-# Bytes of one input block the kernels aim for (a few such blocks, double
-# buffered, plus the in-kernel temporaries must fit the scoped VMEM the TPU
-# compiler grants a kernel by default).
+# Bytes of one input block the kernels aim for, a row block's counted in
+# float32 (a few such blocks, double buffered, plus the in-kernel
+# temporaries must fit the scoped VMEM the TPU compiler grants a kernel by
+# default).
 BLOCK_BYTES = 1 << 20
 
+# The kernels compute in float32 whatever type the grid is stored in.
+COMPUTE_ITEMSIZE = 4
+
 # How many copies of one halo-extended block a stencil kernel holds in VMEM
-# at once: double-buffered inputs and output plus the in-kernel
-# temporaries.  The budget they must fit is the device's
-# (``DeviceProfile.scoped_vmem_bytes``).  Calibrated against the v5e
-# compiler's 16 MiB: a resident 768x768 fp32 grid (2.25 MiB) compiles and
-# 896x896 (3 MiB) does not; a depth-4 trapezoid on rows of 16384 fp32
-# compiles and on rows of 32768 does not.  tests/test_tpu_compile.py keeps
-# the largest admitted cases compiling.
-VMEM_BLOCKS = 7
+# at once: its input and output blocks, double-buffered, in the stored
+# type, and its temporaries in float32.  The budget they must fit is the
+# device's (``DeviceProfile.scoped_vmem_bytes``).  Calibrated against the
+# v5e compiler's 16 MiB: a resident 768x768 fp32 grid (2.25 MiB) compiles
+# and 896x896 (3 MiB) does not; a depth-4 trapezoid on rows of 16384 fp32
+# compiles and on rows of 32768 does not; on rows of 16384 bf16 it compiles
+# in 16-row blocks (48 rows with their halo blocks) and not in 32-row ones
+# (64 rows: 16.43 MiB).  tests/test_tpu_compile.py keeps the largest
+# admitted cases compiling.
+VMEM_IO_BLOCKS = 4
+VMEM_TEMP_BLOCKS = 3
 
 
 def default_interpret(interpret: bool | None) -> bool:
@@ -95,14 +102,15 @@ def row_block(H: int, W: int, halo: int, block_h: int = 256,
     """Rows per block for a grid whose blocks read a ``halo``-deep row halo.
 
     Returns ``H`` when one block holds the whole grid (within ``block_h``
-    rows and ``BLOCK_BYTES`` over ``planes`` stacked (rows, W) slabs);
-    otherwise a multiple of the halo block ``round_up(halo, sublanes)``, so
-    that the neighbours' halo rows are whole aligned blocks.
+    rows and ``BLOCK_BYTES`` over ``planes`` stacked (rows, W) float32
+    slabs, the type the kernels compute in); otherwise a multiple of the
+    halo block ``round_up(halo, sublanes)``, so that the neighbours' halo
+    rows are whole aligned blocks.
     """
     sub = sublanes(itemsize)
     hb = round_up(max(halo, 1), sub)
-    cap = min(block_h,
-              BLOCK_BYTES // (planes * tile_bytes(1, W, itemsize)) * sub)
+    rows = BLOCK_BYTES // (planes * round_up(W, 128) * COMPUTE_ITEMSIZE)
+    cap = min(block_h, rows // sub * sub)
     bh = max(hb, cap // hb * hb)
     return H if round_up(H, sub) <= cap or bh >= H else bh
 
@@ -113,57 +121,62 @@ def fused_block_geometry(H: int, W: int, fuse: int, r: int,
                          planes: int = 1) -> tuple[int, int]:
     """Block geometry of the temporally-fused 2D Jacobi kernel.
 
-    Returns ``(bh, halo)``: rows per block and the per-side halo depth.
-    This is the single source of truth shared by ``jacobi_fused.py`` (which
-    tiles with it) and the ``plan.py`` roofline model (which prices the rim
-    recompute it implies).
+    Returns ``(bh, halo)``: rows per block and the rows each block reads
+    from each of its two neighbours.  This is the single source of truth
+    shared by ``jacobi_fused.py`` (which tiles with it) and ``plan.py``
+    (whose roofline model prices the rim recompute it implies, and whose
+    ``StencilPlan`` records it).
 
-    ``bh == H`` is the *resident* scheme: the whole grid is one block and a
-    depth-``r`` zero rim is re-built between in-kernel iterations, so no work
-    is redundant and the fuse depth is unbounded.  ``rim="resident"`` forces
-    it (legal only where :func:`fits_vmem` admits the whole grid);
-    ``rim="trapezoid"`` picks it
+    ``bh == H`` is the *resident* scheme: the whole grid is one block
+    (``halo == 0``) and a depth-``r`` zero rim is re-built between in-kernel
+    iterations, so no work is redundant and the fuse depth is unbounded.
+    ``rim="resident"`` forces it (legal only where :func:`fits_vmem` admits
+    the whole grid); ``rim="trapezoid"`` picks it
     whenever the grid fits one block and otherwise tiles rows into blocks
     that read a ``fuse * r``-deep halo from their neighbours and recompute
     it (the classic overlapped-tiling scheme).  ``planes`` counts the
     (rows, W) slabs a block carries (1 + the variable taps' weight fields).
     """
     if rim == "resident":
-        return H, r
+        return H, 0
     if rim != "trapezoid":
         raise ValueError(f"unknown rim strategy {rim!r} "
                          f"(expected 'trapezoid' or 'resident')")
     halo = fuse * r
     bh = row_block(H, W, halo, block_h, itemsize, planes)
-    return bh, (r if bh == H else halo)
+    return bh, (0 if bh == H else halo)
 
 
 def block_vmem_bytes(grid_shape: tuple[int, ...], fuse: int, r: int, *,
                      itemsize: int = 4, planes: int = 1,
                      block_h: int | None = None,
                      rim: str = "trapezoid") -> int:
-    """VMEM bytes of one instance's halo-extended block: the 2D fused
-    kernel at depth ``fuse`` (``planes`` = 1 + weight fields), or the 3D
-    step kernel on a (Z, X, Y) grid (``fuse`` is ignored)."""
+    """VMEM bytes a kernel holds for one instance's halo-extended block:
+    ``VMEM_IO_BLOCKS`` copies in the stored type and ``VMEM_TEMP_BLOCKS`` in
+    float32.  The block is the 2D fused kernel's at depth ``fuse``
+    (``planes`` = 1 + weight fields), or the 3D step kernel's on a
+    (Z, X, Y) grid (``fuse`` is ignored)."""
     sub = sublanes(itemsize)
     if len(grid_shape) == 3:
         Z, X, Y = grid_shape
         bx = row_block(X, Y, r, block_h or 64, itemsize, planes=Z * planes)
         rows = X if bx == X else bx + 2 * round_up(r, sub)
-        return Z * planes * tile_bytes(rows, Y, itemsize)
-    H, W = grid_shape
-    bh, halo = fused_block_geometry(H, W, fuse, r, block_h or 256, rim,
-                                    itemsize, planes)
-    rows = H if bh == H else bh + 2 * round_up(halo, sub)
-    return planes * tile_bytes(rows, W, itemsize)
+        planes, W = Z * planes, Y
+    else:
+        H, W = grid_shape
+        bh, halo = fused_block_geometry(H, W, fuse, r, block_h or 256, rim,
+                                        itemsize, planes)
+        rows = bh + 2 * round_up(halo, sub)
+    elements = planes * tile_bytes(rows, W, itemsize) // itemsize
+    return elements * (VMEM_IO_BLOCKS * itemsize
+                       + VMEM_TEMP_BLOCKS * COMPUTE_ITEMSIZE)
 
 
 def fits_vmem(grid_shape: tuple[int, ...], fuse: int, r: int, *,
               budget: int, **kw) -> bool:
     """Whether the kernel for this geometry fits ``budget`` bytes of scoped
-    VMEM (``VMEM_BLOCKS`` copies of its block; ``kw`` as for
-    :func:`block_vmem_bytes`)."""
-    return VMEM_BLOCKS * block_vmem_bytes(grid_shape, fuse, r, **kw) <= budget
+    VMEM (``kw`` as for :func:`block_vmem_bytes`)."""
+    return block_vmem_bytes(grid_shape, fuse, r, **kw) <= budget
 
 
 def fuse_redundancy(grid_shape: tuple[int, int], fuse: int, r: int,
@@ -176,7 +189,7 @@ def fuse_redundancy(grid_shape: tuple[int, int], fuse: int, r: int,
     """
     H, W = grid_shape
     bh, halo = fused_block_geometry(H, W, fuse, r, block_h, rim)
-    return 1.0 if bh == H else (bh + 2 * halo) / bh
+    return (bh + 2 * halo) / bh
 
 
 def halo_fuse_redundancy(local_shape: tuple[int, int], fuse: int,

@@ -31,6 +31,8 @@ TABLE1 = (131072, 64, 64)
 GRID = (1, 8192, 8192)
 FIG6 = (8192, 10, 64, 64)
 HALO = (1, 16384, 16384)
+# the table1-grid-fixed benchmark cell: one chip's 16384^2 grid
+GRID_CELL = (1, 16384, 16384)
 
 
 @pytest.fixture(scope="module")
@@ -116,6 +118,25 @@ def test_widest_admitted_rows(fuse, one_chip, no_compile_cache):
              (1, 256, 16384), sharding=one_chip)
 
 
+def test_sixteen_bit_rows(one_chip, no_compile_cache):
+    # A bfloat16 grid is computed in float32 in VMEM, so its row blocks are
+    # sized as a float32 grid's: rows of 16384 get 16-row blocks, which
+    # compile (32-row ones need 16.43 MiB of scoped VMEM and did not), and
+    # rows of 32768 (20.88 MiB) are refused before they reach the compiler.
+    from repro.core.plan import device_profile
+    from repro.kernels.tiling import fits_vmem, fused_block_geometry
+    spec = laplace_jacobi(2)
+    budget = device_profile(V5E).scoped_vmem_bytes
+    assert fused_block_geometry(256, 16384, 4, 1, itemsize=2) == (16, 4)
+    assert fits_vmem((256, 16384), 4, 1, budget=budget, itemsize=2)
+    assert not fits_vmem((256, 32768), 4, 1, budget=budget, itemsize=2)
+    args = [jax.ShapeDtypeStruct((1, 256, 16384), jnp.bfloat16,
+                                 sharding=one_chip)]
+    compiled = jax.jit(lambda x: jacobi2d_fused_step(
+        x, spec, fuse=4, bc_value=1.0, interpret=False)).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 def test_fused_variable_coefficients(one_chip, no_compile_cache):
     kappa = 1.0 + np.random.default_rng(0).random((1024, 1024), np.float32)
     spec = heterogeneous_jacobi(kappa)
@@ -191,6 +212,30 @@ def test_sweep_loop_copies_no_grid(ndim, shape, iters, calls, one_chip,
     # the loop's two buffers, each lane-padded to 128, as before the pairing
     padded = np.prod(shape[:-1]) * 128 * 4
     assert compiled.memory_analysis().temp_size_in_bytes <= 2 * padded + 2**20
+
+
+def test_grid_sweep_loop(one_chip, no_compile_cache):
+    # The table1-grid-fixed cell's program: 25 fuse-4 passes over one
+    # 16384^2 grid in 16-row trapezoid blocks.  The pairs of calls need no
+    # copy of the grid, and the only temporary beside the argument and the
+    # output is the loop's second buffer (16384 lanes need no padding).
+    solver = Solver(laplace_jacobi(2), GRID_CELL[1:], bc=1.0, rtol=None,
+                    atol=None, max_iters=100, device_kind=V5E,
+                    interpret=False)
+    plan = solver.plan
+    assert (plan.backend, plan.fuse, plan.block_rows, plan.halo_rows) == (
+        "pallas", 4, 16, 4)
+    x = jax.ShapeDtypeStruct(GRID_CELL, jnp.float32, sharding=one_chip)
+    compiled = plan._fn.lower(x, None, None, None).compile()
+    bodies = _while_bodies(compiled.as_text())
+    grid_copy = re.compile(r"f32\[1,16384,16384\]\{[^}]*\} copy\(")
+    assert bodies
+    for body in bodies:
+        assert not grid_copy.search(body)
+        assert body.count('custom_call_target="tpu_custom_call"') == 2
+    grid_bytes = np.prod(GRID_CELL) * 4
+    assert compiled.memory_analysis().temp_size_in_bytes <= \
+        grid_bytes + 2**20
 
 
 def test_halo_on_four_chips(topo, no_compile_cache):
