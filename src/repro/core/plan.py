@@ -540,6 +540,18 @@ class StencilPlan:
     # None for every other path.
     block_rows: int | None = None
     halo_rows: int | None = None
+    # ``tiling.lane_axis`` bound to this plan's grid, spec, boundary, type
+    # and device: what the 2D Jacobi kernel puts on the lanes for a batch
+    # size.  None for every other path.
+    _lane_rule: Callable[[int], str] | None = dataclasses.field(
+        default=None, repr=False)
+
+    def lane_axis(self, batch: int) -> str | None:
+        """What the 2D Pallas kernel puts on the lanes for a batch of
+        ``batch`` instances: "batch" (``jacobi2d_lanes_step``, the batch
+        stored (H, W, batch)) or "cols" (``jacobi2d_fused_step``); None
+        where no 2D Pallas kernel runs."""
+        return None if self._lane_rule is None else self._lane_rule(batch)
 
     def __call__(self, x: jnp.ndarray, *, fields=None, source=None,
                  bc_value=None) -> jnp.ndarray:
@@ -729,14 +741,21 @@ def make_plan(
                 f"grid need more than the scoped VMEM a {device.kind} "
                 f"kernel gets; use a shallower fuse")
 
-    from repro.kernels.tiling import default_interpret, fused_block_geometry
+    from repro.kernels.tiling import (default_interpret, fused_block_geometry,
+                                      lane_axis)
     interpreted = backend in ("pallas", "pallas_fused") \
         and default_interpret(interpret)
-    block_rows = halo_rows = None
+    block_rows = halo_rows = lane_rule = None
     if backend in ("pallas", "pallas_fused") and spec.ndim == 2:
         block_rows, halo_rows = fused_block_geometry(
             *grid_shape, fuse, spec.radius, block_h or 256,
             rim or "trapezoid", itemsize, planes=1 + spec.num_variable_taps)
+        bc_value = _scalar_bc_value(bc)
+
+        def lane_rule(batch):
+            return lane_axis((batch, *grid_shape), spec, bc_value,
+                             itemsize=itemsize,
+                             budget=device.scoped_vmem_bytes)
 
     fn, operands = _build_fn(spec, grid_shape, backend, bc, mode, iters, fuse,
                              dtype, mesh, interpret, block_h, rim)
@@ -750,7 +769,7 @@ def make_plan(
                        mode=mode, iters=iters, fuse=fuse, costs=costs, _fn=fn,
                        interpreted=interpreted, source=source, rim=rim,
                        operands=operands, block_rows=block_rows,
-                       halo_rows=halo_rows)
+                       halo_rows=halo_rows, _lane_rule=lane_rule)
 
 
 def _build_fn(spec, grid_shape, backend, bc, mode, iters, fuse, dtype, mesh,

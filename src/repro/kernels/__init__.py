@@ -10,6 +10,7 @@ from repro.kernels.ops import (
     dense_stencil_matmul,
     jacobi2d,
     jacobi2d_fused_step,
+    jacobi2d_lanes_step,
     jacobi3d,
     stencil2d,
     stencil3d,
@@ -21,6 +22,7 @@ __all__ = [
     "dense_stencil_matmul",
     "jacobi2d",
     "jacobi2d_fused_step",
+    "jacobi2d_lanes_step",
     "jacobi3d",
     "stencil2d",
     "stencil3d",

@@ -26,6 +26,15 @@ Small instances are batched several to a block.
 The iteration is a ``fori_loop`` over a fixed-shape block, so compile time
 does not grow with T.  This one kernel also serves the single step of
 ``stencil2d`` (T=1, input shell left as given).
+
+A batch of tiles narrower than 128 lanes wastes the rest of every row's
+lanes in that layout.  ``jacobi2d_lanes_step`` takes the batch stored
+batch-minor, (H, W, B), instead: each lane holds one tile, so every lane is
+a real point, shifts along H are whole vector registers and shifts along W
+are sublane shifts.  Tiles never meet across lanes, so a block holds whole
+tiles, in groups of 128, resident for all T sweeps, and its zero rim is
+built along H and W only.  ``tiling.lane_axis`` chooses between the two
+kernels by shape.
 """
 from __future__ import annotations
 
@@ -41,6 +50,7 @@ from repro.kernels.tiling import (
     batch_block,
     default_interpret,
     fused_block_geometry,
+    lane_block,
     pad_last2,
     round_up,
     shift2d,
@@ -199,3 +209,82 @@ def jacobi2d_fused_step(
                    block_h=block_h, rim=rim,
                    interpret=default_interpret(interpret),
                    name="jacobi2d_fused_step", fields=fields)
+
+
+def _shift(y: jnp.ndarray, d: int, axis: int) -> jnp.ndarray:
+    """``result[.., i, ..] = y[.., i + d, ..]`` along ``axis``, zero where
+    ``i + d`` leaves the tile."""
+    n = y.shape[axis]
+    if d == 0:
+        return y
+    if abs(d) >= n:
+        return jnp.zeros_like(y)
+    zeros = jnp.zeros(y.shape[:axis] + (abs(d),) + y.shape[axis + 1:],
+                      y.dtype)
+    if d > 0:
+        parts = [jax.lax.slice_in_dim(y, d, n, axis=axis), zeros]
+    else:
+        parts = [zeros, jax.lax.slice_in_dim(y, 0, n + d, axis=axis)]
+    return jnp.concatenate(parts, axis=axis)
+
+
+def _lanes_kernel(x_ref, o_ref, *, spec: StencilSpec, T: int,
+                  bc_value: float):
+    """T sweeps of an (H, W, lanes) block, one whole tile on each lane: the
+    input's Dirichlet shell is pinned, then each sweep sums the taps in
+    ``spec.taps`` order and re-pins the shell."""
+    H, W, L = x_ref.shape
+    bc = np.float32(bc_value)
+
+    def pin(y):
+        cols = jax.lax.broadcasted_iota(jnp.int32, (1, W, L), 1)
+        y = jnp.where((cols < 1) | (cols >= W - 1), bc, y)
+        if H <= 2:
+            return jnp.full_like(y, bc)
+        edge = jnp.full((1, W, L), bc, y.dtype)
+        return jnp.concatenate([edge, y[1:H - 1], edge], axis=0)
+
+    def sweep(_, y):
+        by_col = {}      # y shifted along W, once for each column offset
+        acc = None
+        for (dr, dc), wgt in spec.taps:
+            if dc not in by_col:
+                by_col[dc] = _shift(y, dc, 1)
+            term = _shift(by_col[dc], dr, 0) * np.float32(wgt)
+            acc = term if acc is None else acc + term
+        return pin(acc)
+
+    y = pin(x_ref[...].astype(jnp.float32))
+    o_ref[...] = jax.lax.fori_loop(0, T, sweep, y).astype(o_ref.dtype)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("spec", "fuse", "bc_value", "interpret"))
+def jacobi2d_lanes_step(x: jnp.ndarray, spec: StencilSpec, *, fuse: int,
+                        bc_value: float,
+                        interpret: bool | None = None) -> jnp.ndarray:
+    """``fuse`` Jacobi iterations of a tile batch stored batch-minor,
+    x: (H, W, batch), in one ``pallas_call`` named
+    ``jacobi2d_lanes_step``.
+
+    Pins each tile's Dirichlet shell to ``bc_value`` before the first
+    iteration and after every one, as ``jacobi2d_fused_step`` does on the
+    same batch stored (batch, H, W).  Blocks are (H, W, lanes) with
+    ``lanes`` from ``tiling.lane_block``; a ragged last block's spare lanes
+    hold no tile and are never written back.
+    """
+    if spec.ndim != 2 or spec.is_variable:
+        raise ValueError("jacobi2d_lanes_step needs a 2D scalar-tap spec")
+    H, W, B = x.shape
+    lanes = lane_block(B, H, W, x.dtype.itemsize)
+    block = pl.BlockSpec((H, W, lanes), lambda b: (0, 0, b))
+    return pl.pallas_call(
+        functools.partial(_lanes_kernel, spec=spec, T=fuse,
+                          bc_value=bc_value),
+        grid=(pl.cdiv(B, lanes),),
+        in_specs=[block],
+        out_specs=block,
+        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        interpret=default_interpret(interpret),
+        name="jacobi2d_lanes_step",
+    )(x)

@@ -19,11 +19,13 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.boundary import DirichletBC
+from repro.core.plan import device_profile
 from repro.core.stencil import StencilSpec
 from repro.kernels.dense_stencil import dense_stencil_matmul
-from repro.kernels.jacobi_fused import jacobi2d_fused_step
+from repro.kernels.jacobi_fused import jacobi2d_fused_step, jacobi2d_lanes_step
 from repro.kernels.stencil2d import stencil2d
 from repro.kernels.stencil3d import stencil3d
+from repro.kernels.tiling import lane_axis
 
 
 def sweep_scan(step, x: jnp.ndarray, n: int) -> jnp.ndarray:
@@ -70,12 +72,31 @@ def jacobi2d(
     kernel (halo-replicated per-cell weight blocks) at fuse>1; ``fields``
     optionally overrides the spec's baked per-cell values with a runtime
     (V, H, W) stack (a traced operand — no recompile on value changes).
+
+    A batch of narrow tiles that ``tiling.lane_axis`` puts on the lanes is
+    swept by ``jacobi2d_lanes_step`` on its (H, W, batch) transpose, which
+    XLA stores as it stores the (batch, H, W) argument, so neither
+    transpose moves data; ``block_h`` and ``rim`` do not apply there (its
+    tiles stay resident).
     """
     if iterations % fuse:
         raise ValueError(f"iterations={iterations} not divisible by fuse={fuse}")
     bc = DirichletBC(bc_value)
+    lanes = lane_axis(x0.shape, spec, bc_value, itemsize=x0.dtype.itemsize,
+                      budget=device_profile().scoped_vmem_bytes) == "batch"
     with jax.named_scope("repro.boundary"):
         x = jax.vmap(bc.set_boundary)(x0)
+        if lanes:
+            # a bit cast that XLA fuses into the pin and names the fused op
+            # after, so it shares the pin's scope
+            x = jnp.transpose(x, (1, 2, 0))
+
+    if lanes:
+        def step(t):
+            return jacobi2d_lanes_step(t, spec, fuse=fuse, bc_value=bc_value,
+                                       interpret=interpret)
+        return jnp.transpose(sweep_scan(step, x, iterations // fuse),
+                             (2, 0, 1))
 
     if spec.is_variable and fuse == 1:
         def step(x):
@@ -154,5 +175,6 @@ __all__ = [
     "stencil2d",
     "stencil3d",
     "jacobi2d_fused_step",
+    "jacobi2d_lanes_step",
     "sweep_scan",
 ]

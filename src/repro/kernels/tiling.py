@@ -22,6 +22,10 @@ import jax.numpy as jnp
 # default).
 BLOCK_BYTES = 1 << 20
 
+# Lanes of one vector register: the batch-minor kernel's blocks hold whole
+# groups of this many instances.
+LANES = 128
+
 # The kernels compute in float32 whatever type the grid is stored in.
 COMPUTE_ITEMSIZE = 4
 
@@ -37,6 +41,16 @@ COMPUTE_ITEMSIZE = 4
 # admitted cases compiling.
 VMEM_IO_BLOCKS = 4
 VMEM_TEMP_BLOCKS = 3
+
+
+def lanes_temp_blocks(r: int) -> int:
+    """Float32 temporaries, in blocks, of the batch-minor kernel at radius
+    ``r``: the carried tiles, the running sum and one copy shifted along W
+    for each of up to 2r column offsets.  Calibrated against the v5e
+    compiler's 16 MiB: 64x64 tiles in 128-lane blocks (2 MiB) compile at
+    radius 1 (the compiler asks 15.75 MiB) and not at radius 2 (19.23 MiB);
+    80x64 tiles (2.5 MiB) do not at radius 1 (19.69 MiB)."""
+    return 2 + 2 * r
 
 
 def default_interpret(interpret: bool | None) -> bool:
@@ -73,6 +87,43 @@ def batch_block(B: int, rows: int, cols: int, itemsize: int = 4) -> int:
     """Instances per block: as many (rows, cols) slabs as fit
     ``BLOCK_BYTES`` (at least one, at most the batch)."""
     return max(1, min(B, BLOCK_BYTES // tile_bytes(rows, cols, itemsize)))
+
+
+def lane_block(B: int, H: int, W: int, itemsize: int = 4) -> int:
+    """Instances per block of the batch-minor kernel, whose blocks are
+    (H, W, lanes) slabs of a tile batch stored (H, W, B): whole 128-lane
+    groups, as many as keep the block's float32 slab within
+    ``BLOCK_BYTES`` (at least one group, at most the batch's whole groups,
+    so only the last block can be ragged)."""
+    slab = H * round_up(W, sublanes(itemsize)) * LANES * COMPUTE_ITEMSIZE
+    return LANES * max(1, min(B // LANES, BLOCK_BYTES // slab))
+
+
+def lane_axis(shape: tuple[int, ...], spec, bc_value, *, itemsize: int = 4,
+              budget: int) -> str:
+    """Which axis of a (B, H, W) Jacobi batch the 2D kernel puts on the
+    lanes: "batch" runs ``jacobi2d_lanes_step`` on the batch stored
+    (H, W, B), "cols" runs ``jacobi2d_fused_step`` on rows of W.
+
+    The batch goes on the lanes for a scalar-tap 2D spec with a scalar
+    Dirichlet value, rows that do not fill whole 128-lane groups, at least
+    one whole group of instances filling a larger share of their lanes
+    than the rows fill of theirs, and a (H, W, lanes) block within
+    ``budget`` bytes of scoped VMEM; everything else keeps its rows on the
+    lanes.  The two layouts want opposite things of the lane axis, so the
+    choice is made from the shape alone.
+    """
+    if (spec.ndim != 2 or len(shape) != 3 or spec.is_variable
+            or bc_value is None):
+        return "cols"
+    B, H, W = shape
+    if W % LANES == 0 or B < LANES \
+            or B / round_up(B, LANES) <= W / round_up(W, LANES):
+        return "cols"
+    lanes = lane_block(B, H, W, itemsize)
+    fits = block_vmem_bytes((H, W), 1, spec.radius, itemsize=itemsize,
+                            lanes=lanes) <= budget
+    return "batch" if fits else "cols"
 
 
 def shift2d(xb: jnp.ndarray, dr: int, dc: int, r: int) -> jnp.ndarray:
@@ -150,13 +201,21 @@ def fused_block_geometry(H: int, W: int, fuse: int, r: int,
 def block_vmem_bytes(grid_shape: tuple[int, ...], fuse: int, r: int, *,
                      itemsize: int = 4, planes: int = 1,
                      block_h: int | None = None,
-                     rim: str = "trapezoid") -> int:
+                     rim: str = "trapezoid", lanes: int | None = None) -> int:
     """VMEM bytes a kernel holds for one instance's halo-extended block:
     ``VMEM_IO_BLOCKS`` copies in the stored type and ``VMEM_TEMP_BLOCKS`` in
     float32.  The block is the 2D fused kernel's at depth ``fuse``
     (``planes`` = 1 + weight fields), or the 3D step kernel's on a
-    (Z, X, Y) grid (``fuse`` is ignored)."""
+    (Z, X, Y) grid (``fuse`` is ignored).  With ``lanes`` it is instead the
+    batch-minor kernel's (H, W, lanes) block of ``lanes`` whole (H, W)
+    tiles, W padded to sublanes and the lanes dense, and its temporaries
+    are ``lanes_temp_blocks(r)`` (``fuse`` is ignored: the tiles stay
+    resident)."""
     sub = sublanes(itemsize)
+    if lanes is not None:
+        H, W = grid_shape
+        return H * round_up(W, sub) * lanes * (
+            VMEM_IO_BLOCKS * itemsize + lanes_temp_blocks(r) * COMPUTE_ITEMSIZE)
     if len(grid_shape) == 3:
         Z, X, Y = grid_shape
         bx = row_block(X, Y, r, block_h or 64, itemsize, planes=Z * planes)

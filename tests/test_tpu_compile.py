@@ -178,6 +178,14 @@ def test_table1_converging_loop(one_chip, no_compile_cache):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def _grid_copy(shape):
+    """A ``copy`` of a grid shaped ``shape`` or stored batch-minor, the
+    batch axis last, as the batch-minor kernel takes it."""
+    dims = "|".join(re.escape(",".join(map(str, s)))
+                    for s in (shape, (*shape[1:], shape[0])))
+    return re.compile(rf"f32\[(?:{dims})\]\{{[^}}]*\}} copy\(")
+
+
 def _while_bodies(text):
     """The text of each computation of ``text`` that a ``while`` runs."""
     comps = dict(re.findall(r"^(?:ENTRY )?%([\w.-]+) [^\n]*\{\n(.*?)\n\}$",
@@ -203,8 +211,7 @@ def test_sweep_loop_copies_no_grid(ndim, shape, iters, calls, one_chip,
     x = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
     compiled = program.lower(x, None, None, None).compile()
     bodies = _while_bodies(compiled.as_text())
-    grid_copy = re.compile(
-        re.escape(f"f32[{','.join(map(str, shape))}]") + r"\{[^}]*\} copy\(")
+    grid_copy = _grid_copy(shape)
     assert bodies
     for body in bodies:
         assert not grid_copy.search(body)
@@ -225,8 +232,11 @@ def test_grid_sweep_loop(one_chip, no_compile_cache):
     plan = solver.plan
     assert (plan.backend, plan.fuse, plan.block_rows, plan.halo_rows) == (
         "pallas", 4, 16, 4)
+    assert plan.lane_axis(GRID_CELL[0]) == "cols"
     x = jax.ShapeDtypeStruct(GRID_CELL, jnp.float32, sharding=one_chip)
     compiled = plan._fn.lower(x, None, None, None).compile()
+    assert "%jacobi2d_fused_step" in compiled.as_text()
+    assert "jacobi2d_lanes_step" not in compiled.as_text()
     bodies = _while_bodies(compiled.as_text())
     grid_copy = re.compile(r"f32\[1,16384,16384\]\{[^}]*\} copy\(")
     assert bodies
@@ -236,6 +246,68 @@ def test_grid_sweep_loop(one_chip, no_compile_cache):
     grid_bytes = np.prod(GRID_CELL) * 4
     assert compiled.memory_analysis().temp_size_in_bytes <= \
         grid_bytes + 2**20
+
+
+@pytest.mark.parametrize("shape, fixed", [
+    (TABLE1, True), ((1024, 64, 64), False), ((4096, 64, 64), False),
+], ids=["table1", "converge-1024", "converge-4096"])
+def test_tile_batch_on_the_lanes(shape, fixed, one_chip, no_compile_cache):
+    # The 64x64 tile batches run the batch-minor kernel: XLA already stores
+    # the (batch, 64, 64) argument batch-minor, so the transposes around the
+    # kernel scan are bit casts and no copy of the grid is left, in either
+    # order; Table 1's only temporary is the loop's second compact buffer.
+    # The pin fused with the entry transpose keeps its ``repro.boundary``
+    # scope, which ``boundary_share`` reads from a device trace.
+    solver = Solver(laplace_jacobi(2), shape[1:], bc=1.0,
+                    rtol=None if fixed else 1e-5, atol=None,
+                    max_iters=100 if fixed else 20_000, device_kind=V5E,
+                    interpret=False)
+    assert solver.plan.lane_axis(shape[0]) == "batch"
+    program = solver.plan._fn if fixed else solver._loop
+    x = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    compiled = program.lower(x, None, None, None).compile()
+    text = compiled.as_text()
+    assert "%jacobi2d_lanes_step" in text
+    assert "jacobi2d_fused_step" not in text
+    assert not _grid_copy(shape).search(text)
+    # the pin, fused with the entry transpose, is still read as the pin
+    pins = re.findall(r"= f32\[64,64,%d\]\S* fusion\(.*" % shape[0], text)
+    assert pins and all("repro.boundary" in p for p in pins)
+    if fixed:
+        assert compiled.memory_analysis().temp_size_in_bytes <= \
+            np.prod(shape) * 4 + 2**20
+
+
+def test_widest_admitted_lane_blocks(one_chip, no_compile_cache):
+    # The VMEM model of the batch-minor kernel (kernels/tiling.py
+    # lanes_temp_blocks) admits 64x64 tiles at radius 1 and 48x64 at radius
+    # 2, which compile; 80x64 at radius 1 and 64x64 at radius 2 need more
+    # than the 16 MiB and keep their rows on the lanes.
+    from repro.core import star
+    from repro.core.plan import device_profile
+    from repro.kernels import jacobi2d_lanes_step
+    from repro.kernels.tiling import lane_axis
+    budget = device_profile(V5E).scoped_vmem_bytes
+    r2 = star(2, [0.1, 0.05], center=0.4)
+    assert lane_axis((4096, 80, 64), laplace_jacobi(2), 1.0,
+                     budget=budget) == "cols"
+    assert lane_axis((4096, 64, 64), r2, 1.0, budget=budget) == "cols"
+    for spec, tile in ((laplace_jacobi(2), (64, 64)), (r2, (48, 64))):
+        assert lane_axis((4096, *tile), spec, 1.0, budget=budget) == "batch"
+        _compile(lambda x: jacobi2d_lanes_step(x, spec, fuse=4, bc_value=1.0,
+                                               interpret=False),
+                 (*tile, 4096), sharding=one_chip)
+
+
+def test_sixteen_bit_tiles(one_chip, no_compile_cache):
+    # The bfloat16 control of the Table 1 cell takes the same kernel.
+    solver = Solver(laplace_jacobi(2), TABLE1[1:], bc=1.0, rtol=None,
+                    atol=None, max_iters=100, dtype=jnp.bfloat16,
+                    device_kind=V5E, interpret=False)
+    assert solver.plan.lane_axis(TABLE1[0]) == "batch"
+    x = jax.ShapeDtypeStruct(TABLE1, jnp.bfloat16, sharding=one_chip)
+    compiled = solver.plan._fn.lower(x, None, None, None).compile()
+    assert "%jacobi2d_lanes_step" in compiled.as_text()
 
 
 def test_halo_on_four_chips(topo, no_compile_cache):
