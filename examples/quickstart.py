@@ -59,9 +59,9 @@ def main():
             spec, grid, backend="conv", bc=1.0, mode=BoundaryMode.PAD,
             iters=iters),
         "pallas direct": make_plan(
-            spec, grid, backend="pallas", bc=1.0, iters=iters),
+            spec, grid, backend="pallas", bc=1.0, iters=iters, fuse=1),
         "pallas fused T=4": make_plan(
-            spec, grid, backend="pallas_fused", bc=1.0, iters=iters, fuse=4),
+            spec, grid, backend="pallas", bc=1.0, iters=iters, fuse=4),
     }
     auto = make_plan(spec, grid, backend="auto", bc=1.0, iters=iters)
     plans[f"auto -> {auto.backend}"] = auto

@@ -5,11 +5,13 @@ distribution).  See DESIGN.md §1-2.
 
 The single entry point is the dispatcher in ``plan.py``:
 ``stencil_apply(spec, x, backend="auto", ...)`` routes one ``StencilSpec``
-through any backend (reference oracle, dense, conv, direct Pallas,
-temporally-fused Pallas, sharded halo exchange), choosing via a small cost
-model when ``backend="auto"``; ``make_plan`` prepares a reusable executor and
-``backend_support`` reports which backends are legal for a given cell.  Every
-backend is cross-validated against the oracle in tests/conformance/.
+through any backend (reference oracle, dense, conv, the Pallas kernels
+direct or temporally fused, sharded halo exchange); ``choose_schedule``
+decides the backend and its fuse depth once, from a measured table or a
+small cost model when ``backend="auto"``; ``make_plan`` prepares a reusable
+executor and ``backend_support`` reports which backends are legal for a
+given cell.  Every backend is cross-validated against the oracle in
+tests/conformance/.
 
 The time dimension lives in ``solver.py``: ``solve(spec, x0, ...)`` /
 ``Solver`` run the whole iteration loop to convergence as one compiled
@@ -78,9 +80,10 @@ from repro.core.plan_cache import (
 from repro.core.plan import (
     BACKENDS,
     BackendSupport,
+    Schedule,
     StencilPlan,
     backend_support,
-    choose_backend,
+    choose_schedule,
     make_plan,
     stencil_apply,
 )
@@ -108,6 +111,7 @@ __all__ = [
     "MGResult",
     "Multigrid",
     "PlanCache",
+    "Schedule",
     "SolveResult",
     "Solver",
     "StencilPlan",
@@ -125,7 +129,7 @@ __all__ = [
     "solve",
     "apply_stencil",
     "backend_support",
-    "choose_backend",
+    "choose_schedule",
     "make_plan",
     "stencil_apply",
     "box",

@@ -1,23 +1,24 @@
 """Measured autotuner for the stencil hot path — schedules priced by clock,
 not by roofline.
 
-``choose_backend`` (core/plan.py) prices every backend from an analytic
-roofline; that model cannot see interpret-mode Pallas overheads, cache
-effects, or the real crossover between temporal-fusion rim recompute and HBM
-savings.  This module closes the loop the way the WSE scaling papers do
-(schedule *search*, then persist the winner): it lowers candidate schedules —
+``choose_schedule`` (core/plan.py) prices every backend from an analytic
+roofline where no measurement applies; that model cannot see interpret-mode
+Pallas overheads, cache effects, or the real crossover between
+temporal-fusion rim recompute and HBM savings.  This module closes the loop
+the way the WSE scaling papers do (schedule *search*, then persist the
+winner): it lowers candidate schedules —
 backend × temporal fuse depth × block shape × rim strategy — through
 ``make_plan``, measures each one, and records the results in a versioned
 table keyed by ``(device_kind, spec family, shape bucket, dtype)``.
 
 The committed artifact (``TUNED_stencil.json`` at the repo root) is the
 plan-once/solve-many analogue of Cerebras' compile-once artifact split:
-dispatch (``choose_backend``/``make_plan``/``select_fuse``) consults the
-table *before* the roofline, with nearest-shape-bucket matching and an
-explicit roofline fallback when no entry applies.  Interpret-mode Pallas
-measurements are recorded for the trajectory but structurally tagged
-(``interpreted: true``) and never allowed to win a cell — the mispricing
-family this PR fixes.
+dispatch (``choose_schedule``, which ``make_plan`` and ``Solver`` call)
+consults the table *before* the roofline, with nearest-shape-bucket
+matching and an explicit roofline fallback when no entry applies.
+Interpret-mode Pallas measurements are recorded for the trajectory but
+structurally tagged (``interpreted: true``) and never allowed to win a
+cell — the mispricing family this PR fixes.
 
 Regenerate the table with ``python -m benchmarks.autotune_bench`` and
 validate it with ``python -m repro.core.autotune --check TUNED_stencil.json``
@@ -404,7 +405,7 @@ def schedule_candidates(
         if not backend_support(backend, spec, grid_shape=grid_shape,
                                mode=mode, bc=bc, device_kind=device_kind):
             continue
-        sweeps = backend in ("pallas", "pallas_fused") and spec.ndim == 2 \
+        sweeps = backend == "pallas" and spec.ndim == 2 \
             and not spec.is_variable
         if not sweeps:
             out.append(Candidate(backend))
@@ -450,9 +451,8 @@ def measure_candidate(
         device_kind = current_device_kind()
     plan = make_plan(
         spec, grid_shape, backend=cand.backend, bc=bc, mode=mode,
-        iters=iters, fuse=cand.fuse if cand.rim or cand.fuse > 1 else None,
-        block_h=cand.block_h, rim=cand.rim, dtype=dtype, mesh=mesh,
-        tuned=None)
+        iters=iters, fuse=cand.fuse, block_h=cand.block_h, rim=cand.rim,
+        dtype=dtype, mesh=mesh, tuned=None)
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.standard_normal((batch, *grid_shape)), dtype)
     sec = _median_seconds(plan, x, repeats=repeats)

@@ -9,16 +9,19 @@ of the repo's executable encodings:
   dense         N×N dense-layer matmul, BCs in-matrix (core/dense_encoding.py)
   conv          conv layer; 3D rides Conv2D channels  (core/conv_encoding.py)
   conv3d_native true Conv3D (what the CS-1 lacked)    (core/conv_encoding.py)
-  pallas        direct Pallas stencil kernel          (kernels/stencil{2,3}d.py)
-  pallas_fused  temporally-blocked Pallas kernel      (kernels/jacobi_fused.py)
+  pallas        Pallas kernels: in 2D kernels/jacobi_fused.py at any fuse
+                depth (the direct kernels/stencil2d.py for variable taps at
+                depth 1); in 3D kernels/stencil3d.py, depth 1
   halo          shard_map halo-exchange distribution  (parallel/halo.py)
 
-``backend="auto"`` picks via a small analytic cost model: per-point FLOPs for
-the encoding (core/metrics.py), bytes streamed per iteration, the device
-kind's vector/matmul throughput and memory bandwidth, and the arithmetic-
-intensity boost temporal fusion buys.  ``backend_support`` answers *which
-backends are legal* for a given (spec, grid, boundary mode, device) cell —
-the conformance matrix in tests/conformance/ walks every cell and either
+``choose_schedule`` decides once what a plan call runs — backend, fuse
+depth, rim, block height — from a measured tuned-table entry where one
+applies, else from a small analytic cost model: per-point FLOPs for the
+encoding (core/metrics.py), bytes streamed per iteration, the device kind's
+vector/matmul throughput and memory bandwidth, and the arithmetic-intensity
+boost temporal fusion buys.  ``backend_support`` answers *which backends
+are legal* for a given (spec, grid, boundary mode, device) cell — the
+conformance matrix in tests/conformance/ walks every cell and either
 cross-validates it against the oracle or records the reason it is skipped.
 """
 from __future__ import annotations
@@ -41,9 +44,14 @@ BACKENDS = (
     "conv",
     "conv3d_native",
     "pallas",
-    "pallas_fused",
     "halo",
 )
+
+
+def _check_backend(backend: str) -> None:
+    if backend not in BACKENDS:
+        raise ValueError(
+            f"unknown backend {backend!r} (known: {', '.join(BACKENDS)})")
 
 
 # ---------------------------------------------------------------------------
@@ -83,10 +91,9 @@ def backend_support(
     Returns a BackendSupport whose ``reason`` string is suitable for a test
     skip message — the conformance matrix relies on this being exhaustive.
     ``device_kind`` (None: this process's device) sets the scoped VMEM a
-    Pallas kernel must fit.
+    Pallas kernel must fit.  An unknown ``backend`` raises ValueError.
     """
-    if backend not in BACKENDS:
-        return _no(f"unknown backend {backend!r} (known: {BACKENDS})")
+    _check_backend(backend)
     nd = spec.ndim
     raw = bc is None
     variable = spec.is_variable
@@ -141,9 +148,7 @@ def backend_support(
             return _no("conv3d_native supports the mask trick only")
         return _OK  # variable taps ride the gather trick (one-hot channels)
 
-    if backend in ("pallas", "pallas_fused"):
-        if backend == "pallas_fused" and nd != 2:
-            return _no("temporal fusion kernel is 2D only (jacobi_fused.py)")
+    if backend == "pallas":
         if nd not in (2, 3):
             return _no(f"no {nd}D Pallas kernel (stencil2d/stencil3d only)")
         if not raw and mode is not BoundaryMode.MASK:
@@ -184,7 +189,6 @@ def backend_support(
 
     raise AssertionError(backend)
 
-
 def _pallas_fits(spec: StencilSpec, grid_shape, fuse: int,
                  device: "DeviceProfile", *, block_h: int | None = None,
                  rim: str | None = None, itemsize: int = 4) -> bool:
@@ -195,22 +199,6 @@ def _pallas_fits(spec: StencilSpec, grid_shape, fuse: int,
                      budget=device.scoped_vmem_bytes, itemsize=itemsize,
                      planes=1 + spec.num_variable_taps, block_h=block_h,
                      rim=rim or "trapezoid")
-
-
-def _halo_fuse_legal(fuse: int, spec: StencilSpec,
-                     grid_shape: tuple[int, ...], mesh) -> bool:
-    """Whether a depth-``fuse`` halo schedule is executable on this cell:
-    the exchanged depth ``radius*fuse`` cannot exceed the local tile extent
-    (one exchange phase only reaches the adjacent shard)."""
-    tiling = _mesh_tiling(mesh)
-    if tiling is None:
-        return False
-    n_row, n_col = tiling
-    if grid_shape[0] % n_row or grid_shape[1] % n_col:
-        return False
-    from repro.core.distributed import max_halo_fuse
-    return fuse <= max_halo_fuse(spec.radius, grid_shape[0] // n_row,
-                                 grid_shape[1] // n_col)
 
 
 def _mesh_tiling(mesh) -> tuple[int, int] | None:
@@ -297,16 +285,62 @@ _INTERPRET_PENALTY = 1e4
 _PERMUTE_LATENCY = 2.5e-6
 
 
-def _resolve_fuse(iters: int, spec: StencilSpec, grid_shape,
-                  device: DeviceProfile, *, block_h: int | None = None,
-                  rim: str | None = None, itemsize: int = 4) -> int:
-    """The default depth of pallas_fused for ``iters``: the deepest of 8, 4,
-    2 that divides it and fits ``device``'s scoped VMEM, else 1.  make_plan
-    runs this depth and the cost model prices it — never a phantom deeper
-    fusion."""
-    return next((f for f in (8, 4, 2) if iters % f == 0 and _pallas_fits(
-        spec, grid_shape, f, device, block_h=block_h, rim=rim,
-        itemsize=itemsize)), 1)
+# The depths the roofline chooses among, deepest first: a tie goes deeper.
+_FUSE_DEPTHS = (16, 8, 4, 2, 1)
+
+
+def _fuses(backend: str, spec: StencilSpec) -> bool:
+    """Whether ``backend`` runs ``spec`` in passes of several sweeps: the 2D
+    Pallas kernels, and the halo exchange (one exchange a pass)."""
+    return spec.ndim == 2 and backend in ("pallas", "halo")
+
+
+def fuse_depth(backend: str, spec: StencilSpec, grid_shape, chunk: int,
+               device: DeviceProfile, *, entry=None,
+               mesh_shape: tuple[int, int] | None = None,
+               block_h: int | None = None, rim: str | None = None) -> int:
+    """Sweeps per pass of a plan call that runs ``chunk`` sweeps.
+
+    1 where ``backend`` does not fuse; else a tuned ``entry``'s depth,
+    clamped to a divisor of ``chunk`` (passes end on chunk boundaries) and,
+    for ``halo``, to what the local tile of the (n_row, n_col)
+    ``mesh_shape`` hosts (``max_halo_fuse``); else the whole chunk for the
+    resident rim; else the roofline's cheapest of 16, 8, 4, 2 and 1 among
+    the depths that divide ``chunk`` and fit (the tile, or ``device``'s
+    scoped VMEM): HBM traffic saved against rim recomputed, and for
+    ``halo`` exchanges saved.  Depths are fitted and priced in float32,
+    the type the kernels compute in, which bounds a 16-bit grid's blocks.
+    """
+    if not _fuses(backend, spec):
+        return 1
+    halo = backend == "halo"
+    deepest = chunk
+    if halo:
+        from repro.core.distributed import max_halo_fuse
+        n_row, n_col = mesh_shape or (1, 1)
+        if grid_shape[0] % n_row or grid_shape[1] % n_col:
+            return 1
+        deepest = min(chunk, max_halo_fuse(
+            spec.radius, grid_shape[0] // n_row, grid_shape[1] // n_col))
+
+    def fits(f):
+        return halo or _pallas_fits(spec, grid_shape, f, device,
+                                    block_h=block_h, rim=rim)
+
+    if entry is not None:
+        f = min(entry.fuse, deepest)
+        while chunk % f:
+            f -= 1
+        if fits(f):
+            return f
+    if rim == "resident" and not halo:
+        return chunk
+    return min((f for f in _FUSE_DEPTHS
+                if chunk % f == 0 and f <= deepest and fits(f)),
+               key=lambda f: estimate_seconds(
+                   backend, spec, grid_shape, chunk, device, fuse=f,
+                   mesh_shape=mesh_shape),
+               default=1)
 
 
 def estimate_seconds(
@@ -325,8 +359,8 @@ def estimate_seconds(
     time = max(compute, memory) per iteration; temporal fusion divides the
     streamed bytes by the fuse depth (the whole point of jacobi_fused.py) but
     pays the trapezoid's rim recompute.  ``fuse=None`` prices the depth
-    ``make_plan`` would resolve for ``iters``; passing an explicit depth lets
-    callers (the solver's fuse auto-selection) compare candidate depths.
+    :func:`fuse_depth` gives a plan call of ``iters`` sweeps (the roofline's,
+    with no tuned entry); an explicit depth lets callers compare depths.
 
     For ``halo`` the model adds a communication term per exchange — perimeter
     bytes over ``collective_bw`` plus four ppermute latencies — divided by
@@ -335,6 +369,7 @@ def estimate_seconds(
     n_col) device tiling the perimeter is measured against; None prices a
     1x1 mesh (per-device compute unchanged, latency floor still paid).
     """
+    _check_backend(backend)
     n = int(np.prod(grid_shape))
     n_var = spec.num_variable_taps
     # Read + write the grid once per iteration; per-cell weight fields add
@@ -358,15 +393,14 @@ def estimate_seconds(
             flops = encoding_flops_per_point(spec, "conv")
         compute = flops * n / device.vector_flops
         mem = stream / device.mem_bw
-    else:  # reference / pallas / pallas_fused / halo: direct shifted adds
+    else:  # reference / pallas / halo: direct shifted adds
         flops = encoding_flops_per_point(spec, "direct")
         compute = flops * n / device.vector_flops
         mem = stream / device.mem_bw
         if fuse is None:
-            fuse = _resolve_fuse(iters, spec, grid_shape, device,
-                                 itemsize=itemsize) \
-                if backend == "pallas_fused" and spec.ndim == 2 else 1
-        if backend in ("pallas", "pallas_fused") and fuse > 1 and spec.ndim == 2:
+            fuse = fuse_depth(backend, spec, grid_shape, iters, device,
+                              mesh_shape=mesh_shape)
+        if backend == "pallas" and fuse > 1 and spec.ndim == 2:
             from repro.kernels.tiling import fuse_redundancy
             mem /= fuse  # fuse-depth fewer HBM round-trips ...
             # ... at the price of recomputing the overlapping block rims
@@ -394,100 +428,132 @@ def estimate_seconds(
 
     per_iter = max(compute, mem)
     total = per_iter * iters
-    if backend in ("pallas", "pallas_fused") and not device.pallas_native:
+    if backend == "pallas" and not device.pallas_native:
         total *= _INTERPRET_PENALTY
     return total
 
 
-def choose_backend(
+@dataclasses.dataclass(frozen=True)
+class Schedule:
+    """What every call of a plan runs: decided once by
+    :func:`choose_schedule`, built by :func:`make_plan`."""
+
+    backend: str
+    fuse: int              # sweeps a pass; 1 where the backend does not fuse
+    rim: str | None        # the 2D Pallas trapezoid geometry, else None
+    block_h: int | None    # the Pallas block height; None: the kernel's
+    # Where the backend came from: "explicit" (the caller named it), "tuned"
+    # (a measured tuned-table entry) or "roofline" (the cost model).
+    source: str
+    # Per-backend seconds the choice was made by (empty when explicit).
+    costs: dict[str, float] = dataclasses.field(default_factory=dict)
+
+
+def choose_schedule(
     spec: StencilSpec,
     grid_shape: tuple[int, ...],
     *,
+    backend: str = "auto",
+    chunk: int = 1,
+    iters: int | None = None,
     mode: BoundaryMode = BoundaryMode.MASK,
     bc: DirichletBC | float | None = 0.0,
-    iters: int = 1,
-    device_kind: str | None = None,
     mesh=None,
     fuse: int | None = None,
+    block_h: int | None = None,
+    rim: str | None = None,
     dtype=jnp.float32,
     interpret: bool | None = None,
+    device_kind: str | None = None,
     tuned="default",
-) -> tuple[str, dict[str, float]]:
-    """Pick the cheapest supported backend; returns (name, cost table).
+    backends: tuple[str, ...] | None = None,
+) -> Schedule:
+    """The schedule of a plan whose every call runs ``chunk`` sweeps.
 
-    Measured entries take priority over the roofline: when the tuned table
-    (``tuned="default"`` → the committed ``TUNED_stencil.json``; pass a
-    ``TunedTable`` to override or ``None`` to disable) holds measurements
-    for this (device, family, shape-bucket, dtype) cell, the returned cost
-    table contains those *measured* per-backend seconds and the pick is
-    their argmin — interpret-mode measurements are structurally excluded, so
-    an interpreted Pallas run can never be priced as a compiled one.  When
-    no entry applies (unknown cell, stale table, unsupported backend) the
-    analytic roofline below is the explicit fallback.
+    The backend is the caller's, or for "auto" the cheapest of ``backends``
+    over ``iters`` sweeps (default ``chunk``; a solver prices its whole
+    budget).  Measurements come first: where the tuned table
+    (``tuned="default"`` → the committed ``TUNED_stencil.json``; a
+    ``TunedTable``; ``None`` disables it) holds compiled entries of
+    supported backends for this (device, family, shape bucket, dtype) cell,
+    the pick is their argmin (an interpreted Pallas run is never priced as
+    a compiled one).  Otherwise :func:`estimate_seconds` prices each
+    supported backend at the depth a chunk runs; ``interpret=True`` makes
+    Pallas pay the interpreter penalty whatever the device.
 
-    Two backends are special-cased: ``halo`` is a *distribution strategy*,
-    not a local encoding, so it is only considered when a mesh is explicitly
-    supplied; ``reference`` is the cross-validation oracle, so auto only
-    falls back to it when no real encoding supports the cell (otherwise
-    "auto matches the oracle" would be circular).
+    ``backends`` defaults to all but two: ``halo``, a distribution strategy,
+    counts only with a mesh, and ``reference``, the cross-validation
+    oracle, only where nothing else is legal (else "auto matches the
+    oracle" would be circular).
 
-    ``fuse`` prices the Pallas paths at an explicit temporal depth (e.g. the
-    deepest depth the caller's chunking can actually run — the solver passes
-    this); None prices the depth make_plan itself would resolve for
-    ``iters``.  ``interpret=True`` declares that any Pallas plan built from
-    this choice will be forced into interpret mode, so the Pallas paths are
-    priced with the interpreter penalty regardless of the device profile.
+    The depth is ``fuse``, else :func:`fuse_depth`'s; ``block_h`` and
+    ``rim`` the caller's, else the chosen backend's tuned entry's.  The
+    tuned table is read once.
     """
+    if backend != "auto":
+        _check_backend(backend)
     device = device_profile(device_kind)
-    device_kind = device.kind
     mesh_shape = _mesh_tiling(mesh) if mesh is not None else None
+    iters = chunk if iters is None else iters
 
-    # -- measured table first ---------------------------------------------
+    # The cell's compiled measurements: the fastest entry of each backend.
     from repro.core import autotune
     table = autotune.resolve_table(tuned)
-    if table is not None and len(table):
-        cell = table.lookup_cell(device_kind, autotune.spec_family(spec),
-                                 tuple(grid_shape), autotune.dtype_key(dtype),
-                                 mesh_shape=mesh_shape)
-        measured: dict[str, float] = {}
-        for e in cell:
-            if e.interpreted or e.backend in measured and \
-                    e.seconds(iters) >= measured[e.backend]:
-                continue
-            if e.backend == "halo" and mesh is None:
-                continue
-            if not backend_support(e.backend, spec, grid_shape=grid_shape,
-                                   mode=mode, bc=bc, mesh=mesh,
-                                   device_kind=device_kind):
-                continue
-            measured[e.backend] = e.seconds(iters)
-        if measured:
-            best = min(measured, key=measured.__getitem__)
-            return best, measured
+    measured: dict = {}
+    for e in table.lookup_cell(device.kind, autotune.spec_family(spec),
+                               tuple(grid_shape), autotune.dtype_key(dtype),
+                               mesh_shape=mesh_shape) if table else ():
+        if not e.interpreted and (e.backend not in measured or e.us_per_iter
+                                  < measured[e.backend].us_per_iter):
+            measured[e.backend] = e
 
-    # -- explicit roofline fallback ---------------------------------------
     costs: dict[str, float] = {}
-    for b in BACKENDS:
-        if b == "halo" and mesh is None:
-            continue
-        if b == "reference":
-            continue
-        if not backend_support(b, spec, grid_shape=grid_shape, mode=mode,
-                               bc=bc, mesh=mesh, device_kind=device_kind):
-            continue
-        costs[b] = estimate_seconds(b, spec, grid_shape, iters, device,
-                                    fuse=fuse,
-                                    mesh_shape=mesh_shape if b == "halo"
-                                    else None)
-        if interpret is True and b in ("pallas", "pallas_fused") \
-                and device.pallas_native:
-            costs[b] *= _INTERPRET_PENALTY
-    if not costs:
-        # Oracle fallback: always legal, never preferred.
-        costs["reference"] = estimate_seconds("reference", spec, grid_shape,
-                                              iters, device)
-    best = min(costs, key=costs.__getitem__)
-    return best, costs
+    source = "explicit"
+    if backend == "auto":
+        if backends is None:
+            backends = [b for b in BACKENDS if b != "reference"
+                        and (b != "halo" or mesh is not None)]
+        legal = [b for b in backends
+                 if backend_support(b, spec, grid_shape=grid_shape, mode=mode,
+                                    bc=bc, mesh=mesh, device_kind=device.kind)]
+        source = "tuned"
+        costs = {b: measured[b].seconds(iters) for b in legal
+                 if b in measured}
+        if not costs:
+            source = "roofline"
+            for b in legal:
+                costs[b] = estimate_seconds(
+                    b, spec, grid_shape, iters, device,
+                    fuse=fuse or fuse_depth(
+                        b, spec, grid_shape, chunk, device,
+                        mesh_shape=mesh_shape, block_h=block_h, rim=rim),
+                    mesh_shape=mesh_shape if b == "halo" else None)
+                if interpret is True and b == "pallas" \
+                        and device.pallas_native:
+                    costs[b] *= _INTERPRET_PENALTY
+        if not costs:
+            # Oracle fallback: always legal, never preferred.
+            costs["reference"] = estimate_seconds(
+                "reference", spec, grid_shape, iters, device)
+        backend = min(costs, key=costs.__getitem__)
+
+    # A measured entry carries the whole schedule, not just the backend: its
+    # depth, block height and rim fill what the caller left open.
+    entry = measured.get(backend)
+    if entry is not None:
+        block_h = entry.block_h if block_h is None else block_h
+        rim = entry.rim if rim is None else rim
+    if not _fuses(backend, spec):
+        return Schedule(backend, 1, None, block_h, source, costs)
+    if fuse is None:
+        fuse = fuse_depth(backend, spec, grid_shape, chunk, device,
+                          entry=entry, mesh_shape=mesh_shape,
+                          block_h=block_h, rim=rim)
+    if backend == "halo":
+        rim = None  # depth-vs-tile legality is make_halo_runner's check
+    elif rim is None and fuse > 1:
+        rim = "trapezoid"
+    return Schedule(backend, fuse, rim, block_h, source, costs)
 
 
 # ---------------------------------------------------------------------------
@@ -635,7 +701,7 @@ def make_plan(
     spec: StencilSpec,
     grid_shape: tuple[int, ...],
     *,
-    backend: str = "auto",
+    backend: str | Schedule = "auto",
     bc: DirichletBC | float | None = 0.0,
     mode: BoundaryMode = BoundaryMode.MASK,
     iters: int = 1,
@@ -650,13 +716,14 @@ def make_plan(
 ) -> StencilPlan:
     """Lower ``spec`` on ``grid_shape`` through one backend into a callable.
 
-    backend="auto" routes through :func:`choose_backend` — a measured
-    tuned-table entry (``tuned``) supplies the whole schedule (backend, fuse
-    depth, block shape, rim strategy) when one applies; the roofline is the
-    fallback.  ``bc=None`` means raw zero-padded stencil application (no
+    The plan's calls run ``iters`` sweeps on the schedule
+    :func:`choose_schedule` decides from ``backend`` ("auto" or a name),
+    ``fuse``, ``block_h``, ``rim`` and ``tuned``; a ``Schedule`` passed as
+    ``backend`` is built as it stands (it carries its own depth, block and
+    rim).  ``bc=None`` means raw zero-padded stencil application (no
     Dirichlet fixup) — only the reference and Pallas backends can express
-    it.  ``block_h``/``rim`` tune the 2D Pallas block geometry (other
-    backends ignore them).
+    it.  ``block_h``/``rim`` tune the Pallas block geometry (other backends
+    ignore them).
     """
     if spec.ndim != len(grid_shape):
         raise ValueError(f"spec is {spec.ndim}D but grid is {len(grid_shape)}D")
@@ -668,35 +735,18 @@ def make_plan(
         raise ValueError("iters must be >= 1")
     bc = _as_bc(bc)
 
-    costs: dict[str, float] = {}
-    source = "explicit"
-    if backend == "auto":
-        backend, costs = choose_backend(
-            spec, grid_shape, mode=mode, bc=bc, iters=iters,
-            device_kind=device_kind, mesh=mesh, dtype=dtype,
-            interpret=interpret, tuned=tuned)
-        source = "roofline"
-        # A measured entry carries the whole schedule, not just the backend:
-        # inherit its fuse depth / block shape / rim strategy where the
-        # caller left them open.
-        from repro.core import autotune
-        table = autotune.resolve_table(tuned)
-        entry = table.lookup(
-            device_profile(device_kind).kind, autotune.spec_family(spec),
-            tuple(grid_shape), autotune.dtype_key(dtype),
-            mesh_shape=_mesh_tiling(mesh) if mesh is not None else None) \
-            if table else None
-        if entry is not None and entry.backend == backend:
-            source = "tuned"
-            if fuse is None and entry.fuse > 1 and iters % entry.fuse == 0 \
-                    and (backend != "halo"
-                         or _halo_fuse_legal(entry.fuse, spec, grid_shape,
-                                             mesh)):
-                fuse = entry.fuse
-            if block_h is None:
-                block_h = entry.block_h
-            if rim is None:
-                rim = entry.rim
+    if isinstance(backend, Schedule):
+        if (fuse, block_h, rim) != (None, None, None):
+            raise ValueError(
+                "a Schedule carries its own fuse, block_h and rim")
+        sched = backend
+    else:
+        sched = choose_schedule(
+            spec, grid_shape, backend=backend, chunk=iters, mode=mode, bc=bc,
+            mesh=mesh, fuse=fuse, block_h=block_h, rim=rim, dtype=dtype,
+            interpret=interpret, device_kind=device_kind, tuned=tuned)
+    backend, fuse, rim, block_h = (sched.backend, sched.fuse, sched.rim,
+                                   sched.block_h)
     sup = backend_support(backend, spec, grid_shape=grid_shape, mode=mode,
                           bc=bc, mesh=mesh, device_kind=device_kind)
     if not sup:
@@ -704,49 +754,26 @@ def make_plan(
     device = device_profile(device_kind)
     itemsize = jnp.dtype(dtype).itemsize
 
-    # ``fuse`` is a hint for the 2D Pallas paths (both scalar-bc and raw
-    # execute in fuse-sized chunks) and for halo (one deep-halo exchange per
-    # ``fuse`` local iterations); every other backend ignores it and the
-    # plan records fuse=1 so its metadata reflects what actually runs.
-    if backend == "halo":
-        rim = None  # depth-vs-tile legality is make_halo_runner's check
-        if fuse is None:
-            fuse = 1
-        elif iters % fuse:
-            raise ValueError(f"iters={iters} not divisible by fuse={fuse}")
-    else:
-        fusing = backend == "pallas_fused" or (backend == "pallas"
-                                               and spec.ndim == 2)
-        if not fusing:
-            fuse = 1
-            rim = None
-        elif fuse is None:
-            if rim == "resident":
-                fuse = iters  # the whole chunk stays resident in VMEM
-            elif backend == "pallas_fused":
-                fuse = _resolve_fuse(iters, spec, grid_shape, device,
+    # ``fuse`` groups the sweeps of the 2D Pallas paths (both scalar-bc and
+    # raw execute in fuse-sized passes) and of halo (one deep-halo exchange
+    # per ``fuse`` local iterations); every other backend's schedule records
+    # fuse=1, what actually runs.
+    if iters % fuse:
+        raise ValueError(f"iters={iters} not divisible by fuse={fuse}")
+    pallas2d = backend == "pallas" and spec.ndim == 2
+    if pallas2d and not _pallas_fits(spec, grid_shape, fuse, device,
                                      block_h=block_h, rim=rim,
-                                     itemsize=itemsize)
-            else:
-                fuse = 1
-        elif iters % fuse:
-            raise ValueError(f"iters={iters} not divisible by fuse={fuse}")
-        if fusing and rim is None and fuse > 1:
-            rim = "trapezoid"
-        if fusing and spec.ndim == 2 and not _pallas_fits(
-                spec, grid_shape, fuse, device, block_h=block_h, rim=rim,
-                itemsize=itemsize):
-            raise ValueError(
-                f"fuse={fuse} {rim or 'trapezoid'} blocks of a {grid_shape} "
-                f"grid need more than the scoped VMEM a {device.kind} "
-                f"kernel gets; use a shallower fuse")
+                                     itemsize=itemsize):
+        raise ValueError(
+            f"fuse={fuse} {rim or 'trapezoid'} blocks of a {grid_shape} "
+            f"grid need more than the scoped VMEM a {device.kind} "
+            f"kernel gets; use a shallower fuse")
 
     from repro.kernels.tiling import (default_interpret, fused_block_geometry,
                                       lane_axis)
-    interpreted = backend in ("pallas", "pallas_fused") \
-        and default_interpret(interpret)
+    interpreted = backend == "pallas" and default_interpret(interpret)
     block_rows = halo_rows = lane_rule = None
-    if backend in ("pallas", "pallas_fused") and spec.ndim == 2:
+    if pallas2d:
         block_rows, halo_rows = fused_block_geometry(
             *grid_shape, fuse, spec.radius, block_h or 256,
             rim or "trapezoid", itemsize, planes=1 + spec.num_variable_taps)
@@ -758,7 +785,7 @@ def make_plan(
                              budget=device.scoped_vmem_bytes)
 
     fn, operands = _build_fn(spec, grid_shape, backend, bc, mode, iters, fuse,
-                             dtype, mesh, interpret, block_h, rim)
+                             dtype, mesh, interpret, block_h, rim, lane_rule)
     # One jit over the whole closure: the per-call preamble (conv-kernel
     # build, set_boundary, mask/bc grids, halo sharding constraint) traces
     # into constants, so repeated plan calls pay only compiled execution.
@@ -766,18 +793,20 @@ def make_plan(
     # operand is a structure change, so each used combination compiles once.
     fn = jax.jit(fn)
     return StencilPlan(spec=spec, backend=backend, grid_shape=grid_shape,
-                       mode=mode, iters=iters, fuse=fuse, costs=costs, _fn=fn,
-                       interpreted=interpreted, source=source, rim=rim,
-                       operands=operands, block_rows=block_rows,
+                       mode=mode, iters=iters, fuse=fuse, costs=sched.costs,
+                       _fn=fn, interpreted=interpreted, source=sched.source,
+                       rim=rim, operands=operands, block_rows=block_rows,
                        halo_rows=halo_rows, _lane_rule=lane_rule)
 
 
 def _build_fn(spec, grid_shape, backend, bc, mode, iters, fuse, dtype, mesh,
-              interpret, block_h=None, rim=None):
+              interpret, block_h=None, rim=None, lane_rule=None):
     """One closure per backend; all share (batch, *grid) -> same semantics.
 
     Returns ``(fn, operands)``: ``fn(x, fields, source, bc_value)`` and the
     frozenset of runtime-operand names this cell supports (see StencilPlan).
+    ``lane_rule`` gives the 2D Jacobi kernel its lane axis from the batch
+    size (``StencilPlan._lane_rule``).
     """
     # Imports deferred so importing repro.core never drags in the Pallas /
     # shard_map machinery for users who only want the specs.
@@ -858,7 +887,7 @@ def _build_fn(spec, grid_shape, backend, bc, mode, iters, fuse, dtype, mesh,
                                       source=source, bc_value=bc_value),
                 frozenset(("source", "bc_value")))
 
-    if backend in ("pallas", "pallas_fused"):
+    if backend == "pallas":
         from repro.kernels.ops import sweep_scan
         bc_value_s = _scalar_bc_value(bc)
         rim = rim or "trapezoid"
@@ -884,7 +913,8 @@ def _build_fn(spec, grid_shape, backend, bc, mode, iters, fuse, dtype, mesh,
             return (lambda x, fields, source, bc_value:
                     jacobi2d(x.astype(dtype), spec, bc_value=bc_value_s,
                              iterations=iters, fuse=fuse, interpret=interpret,
-                             rim=rim, fields=fields, **kw2d),
+                             rim=rim, fields=fields,
+                             lane_axis=lane_rule(x.shape[0]), **kw2d),
                     var_ops)
         if spec.is_variable:
             from repro.kernels import stencil2d

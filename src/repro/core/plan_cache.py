@@ -478,8 +478,8 @@ class PlanCache:
     def _bucket_backend(self, template: StencilSpec, bucket, dtype,
                         interpret, device_kind) -> str:
         from repro.core import autotune
-        from repro.core.plan import (backend_support, device_profile,
-                                     estimate_seconds, make_plan)
+        from repro.core.plan import (backend_support, choose_schedule,
+                                     make_plan)
         nd = template.ndim
         cands = [b for b in _PAD_BACKENDS.get(nd, ("reference",))
                  if backend_support(b, template, grid_shape=bucket,
@@ -498,16 +498,10 @@ class PlanCache:
                 return self._probe_winners[memo_key]
 
         if not self.probe:
-            device = device_profile(device_kind)
-            table = autotune.resolve_table(self.tuned)
-            if table is not None and len(table):
-                entry = table.lookup(
-                    device.kind, autotune.spec_family(template),
-                    tuple(bucket), autotune.dtype_key(dtype))
-                if entry is not None and entry.backend in cands:
-                    return entry.backend
-            return min(cands, key=lambda b: estimate_seconds(
-                b, template, tuple(bucket), 100, device))
+            return choose_schedule(
+                template, tuple(bucket), chunk=100, bc=DirichletBC(0.0),
+                dtype=dtype, device_kind=device_kind, tuned=self.tuned,
+                backends=tuple(cands)).backend
 
         # Measured probe: a short var-operand plan per candidate, timed
         # after one warmup (the warmup absorbs compilation).  A candidate

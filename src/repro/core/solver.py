@@ -31,9 +31,9 @@ mesh runs each chunk as the shard_map halo-exchange program from
 ``core/distributed.py``, with residuals computed on the sharded global
 array — the whole distributed time loop is still one compiled program.
 
-For the 2D Pallas paths the temporal fuse depth is auto-selected against the
-PR 1 roofline model (``estimate_seconds(..., fuse=...)`` prices each depth's
-HBM-traffic saving against its trapezoid rim recompute).
+The schedule (backend, fuse depth, rim, block height) is decided once, by
+``core/plan.py::choose_schedule``: backends are priced over the whole
+``max_iters`` budget, each at the depth a ``check_every`` chunk runs.
 """
 from __future__ import annotations
 
@@ -47,17 +47,9 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from repro.core.boundary import BoundaryMode, DirichletBC
-from repro.core.plan import (
-    StencilPlan,
-    _pallas_fits,
-    choose_backend,
-    device_profile,
-    estimate_seconds,
-    make_plan,
-)
+from repro.core.plan import StencilPlan, choose_schedule, make_plan
 from repro.core.stencil import StencilSpec
 
-_FUSE_CANDIDATES = (16, 8, 4, 2, 1)
 _DEFAULT_CHECK_EVERY = 16
 
 
@@ -94,63 +86,6 @@ class SolveResult:
     check_every: int
     wall_seconds: float
     costs: dict[str, float]
-
-
-def select_fuse(backend: str, spec: StencilSpec, grid_shape: tuple[int, ...],
-                check_every: int, device_kind: str | None = None,
-                tuned="default", dtype=jnp.float32, mesh=None) -> int | None:
-    """Temporal fuse depth for one chunk: measured if tuned, else roofline.
-
-    The 2D Pallas paths and ``halo`` fuse; every other backend gets ``None``
-    (the plan records fuse=1).  A tuned-table entry for this cell whose
-    backend matches supplies the measured depth first (clamped to the
-    largest divisor of ``check_every`` so chunk boundaries land on whole
-    fused passes); the roofline model prices the candidate depths otherwise.
-
-    For ``halo`` the depth is additionally clamped to what the local tile
-    can host (``max_halo_fuse``) on the (n_row, n_col) tiling of ``mesh``,
-    tuned entries are matched mesh-exactly, and the roofline prices the
-    communication term each depth divides.
-    """
-    halo = backend == "halo" and spec.ndim == 2
-    if not halo and (backend not in ("pallas", "pallas_fused")
-                     or spec.ndim != 2):
-        return None
-    device = device_profile(device_kind)
-
-    mesh_shape = deepest = None
-    if halo:
-        from repro.core.distributed import max_halo_fuse
-        from repro.core.plan import _mesh_tiling
-        mesh_shape = _mesh_tiling(mesh) if mesh is not None else None
-        n_row, n_col = mesh_shape or (1, 1)
-        if grid_shape[0] % n_row or grid_shape[1] % n_col:
-            return None
-        deepest = max_halo_fuse(spec.radius, grid_shape[0] // n_row,
-                                grid_shape[1] // n_col)
-
-    from repro.core import autotune
-    table = autotune.resolve_table(tuned)
-    if table is not None and len(table):
-        entry = table.lookup(device.kind, autotune.spec_family(spec),
-                             tuple(grid_shape), autotune.dtype_key(dtype),
-                             mesh_shape=mesh_shape)
-        if entry is not None and entry.backend == backend and entry.fuse >= 1:
-            f = min(entry.fuse, check_every)
-            if deepest is not None:
-                f = min(f, deepest)
-            while check_every % f:
-                f -= 1
-            if halo or _pallas_fits(spec, grid_shape, f, device):
-                return f
-
-    candidates = [f for f in _FUSE_CANDIDATES if check_every % f == 0
-                  and (deepest is None or f <= deepest)
-                  and (halo or _pallas_fits(spec, grid_shape, f, device))] or [1]
-    return min(candidates,
-               key=lambda f: estimate_seconds(backend, spec, grid_shape,
-                                              check_every, device, fuse=f,
-                                              mesh_shape=mesh_shape))
 
 
 class Solver:
@@ -224,57 +159,19 @@ class Solver:
                                 else min(int(check_every), self.max_iters))
         self.n_chunks = max(1, self.max_iters // self.check_every)
 
-        self.costs: dict[str, float] = {}
-        was_auto = backend == "auto"
-        if backend == "auto":
-            # Price the whole solve (max_iters), not one chunk — fusion and
-            # fixed per-iteration overheads amortize over the full loop —
-            # but at a fuse depth a check_every-sized chunk can actually run,
-            # not the phantom depth _resolve_fuse(max_iters) would pick.
-            pricing_fuse = fuse
-            if pricing_fuse is None:
-                pricing_fuse = select_fuse("pallas_fused", spec,
-                                           self.grid_shape, self.check_every,
-                                           device_kind, tuned=tuned)
-            backend, self.costs = choose_backend(
-                spec, self.grid_shape, mode=mode, bc=bc,
-                iters=self.max_iters, device_kind=device_kind, mesh=mesh,
-                fuse=pricing_fuse, dtype=dtype, interpret=interpret,
-                tuned=tuned)
-
-        if fuse is None:
-            fuse = select_fuse(backend, spec, self.grid_shape,
-                               self.check_every, device_kind, tuned=tuned,
-                               dtype=dtype, mesh=mesh)
-        # A measured entry for this cell carries the rest of the schedule
-        # (block shape, rim strategy) beside the fuse depth select_fuse
-        # already took from it.
-        block_h = rim = None
-        entry = None
-        from repro.core import autotune
-        from repro.core.plan import _mesh_tiling
-        table = autotune.resolve_table(tuned)
-        if table is not None and len(table):
-            entry = table.lookup(
-                device_profile(device_kind).kind,
-                autotune.spec_family(spec), self.grid_shape,
-                autotune.dtype_key(dtype),
-                mesh_shape=_mesh_tiling(mesh) if mesh is not None else None)
-            if entry is not None and entry.backend == backend:
-                block_h, rim = entry.block_h, entry.rim
-        # (an explicit fuse that does not divide check_every is rejected by
-        # make_plan's iters/fuse divisibility check)
+        # Price the whole solve (max_iters), not one chunk — fusion and
+        # fixed per-iteration overheads amortize over the full loop — with
+        # each backend at the depth a check_every chunk runs.
+        sched = choose_schedule(
+            spec, self.grid_shape, backend=backend, chunk=self.check_every,
+            iters=self.max_iters, mode=mode, bc=bc, mesh=mesh, fuse=fuse,
+            dtype=dtype, interpret=interpret, device_kind=device_kind,
+            tuned=tuned)
+        self.costs: dict[str, float] = sched.costs
         self.plan: StencilPlan = make_plan(
-            spec, self.grid_shape, backend=backend, bc=bc, mode=mode,
-            iters=self.check_every, fuse=fuse, dtype=dtype, mesh=mesh,
-            interpret=interpret, device_kind=device_kind, tuned=tuned,
-            block_h=block_h, rim=rim)
-        if was_auto:
-            # The solver resolved "auto" itself (to price the whole solve),
-            # so the plan saw an explicit backend name — restore where the
-            # choice actually came from.
-            self.plan.source = ("tuned" if entry is not None
-                                and entry.backend == backend else "roofline")
+            spec, self.grid_shape, backend=sched, bc=bc, mode=mode,
+            iters=self.check_every, dtype=dtype, mesh=mesh,
+            interpret=interpret, device_kind=device_kind)
         self.backend = self.plan.backend
         self.fuse = self.plan.fuse
         if not self.fixed:

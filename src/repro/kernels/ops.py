@@ -19,13 +19,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.boundary import DirichletBC
-from repro.core.plan import device_profile
 from repro.core.stencil import StencilSpec
 from repro.kernels.dense_stencil import dense_stencil_matmul
 from repro.kernels.jacobi_fused import jacobi2d_fused_step, jacobi2d_lanes_step
 from repro.kernels.stencil2d import stencil2d
 from repro.kernels.stencil3d import stencil3d
-from repro.kernels.tiling import lane_axis
 
 
 def sweep_scan(step, x: jnp.ndarray, n: int) -> jnp.ndarray:
@@ -48,7 +46,7 @@ def sweep_scan(step, x: jnp.ndarray, n: int) -> jnp.ndarray:
 @functools.partial(
     jax.jit,
     static_argnames=("spec", "iterations", "fuse", "block_h", "bc_value",
-                     "interpret", "rim"),
+                     "interpret", "rim", "lane_axis"),
 )
 def jacobi2d(
     x0: jnp.ndarray,
@@ -61,6 +59,7 @@ def jacobi2d(
     interpret: bool | None = None,
     rim: str = "trapezoid",
     fields: jnp.ndarray | None = None,
+    lane_axis: str = "cols",
 ) -> jnp.ndarray:
     """``iterations`` Jacobi steps on (batch, H, W) via the Pallas kernels.
 
@@ -73,17 +72,20 @@ def jacobi2d(
     optionally overrides the spec's baked per-cell values with a runtime
     (V, H, W) stack (a traced operand — no recompile on value changes).
 
-    A batch of narrow tiles that ``tiling.lane_axis`` puts on the lanes is
-    swept by ``jacobi2d_lanes_step`` on its (H, W, batch) transpose, which
-    XLA stores as it stores the (batch, H, W) argument, so neither
-    transpose moves data; ``block_h`` and ``rim`` do not apply there (its
-    tiles stay resident).
+    ``lane_axis="batch"`` (what ``tiling.lane_axis`` gives a batch of
+    narrow tiles; a plan passes its own choice) sweeps the batch with
+    ``jacobi2d_lanes_step`` on its (H, W, batch) transpose, which XLA
+    stores as it stores the (batch, H, W) argument, so neither transpose
+    moves data; ``block_h`` and ``rim`` do not apply there (its tiles stay
+    resident).  "cols" keeps each row on the lanes.
     """
     if iterations % fuse:
         raise ValueError(f"iterations={iterations} not divisible by fuse={fuse}")
+    if lane_axis not in ("batch", "cols"):
+        raise ValueError(f"lane_axis must be 'batch' or 'cols', got "
+                         f"{lane_axis!r}")
     bc = DirichletBC(bc_value)
-    lanes = lane_axis(x0.shape, spec, bc_value, itemsize=x0.dtype.itemsize,
-                      budget=device_profile().scoped_vmem_bytes) == "batch"
+    lanes = lane_axis == "batch"
     with jax.named_scope("repro.boundary"):
         x = jax.vmap(bc.set_boundary)(x0)
         if lanes:

@@ -21,10 +21,11 @@ from repro.core import (
     backend_support,
     box,
     causal_conv1d_spec,
-    choose_backend,
+    choose_schedule,
     heterogeneous_jacobi,
     jacobi_reference,
     laplace_jacobi,
+    make_plan,
     star,
     stencil_apply,
     variable_coefficient,
@@ -67,6 +68,12 @@ SPECS = {
 }
 
 MODES = (BoundaryMode.MASK, BoundaryMode.PAD, BoundaryMode.MATRIX)
+# Every backend one sweep a pass, and the 2D Pallas kernel once more at a
+# fused depth (the temporally-blocked trapezoid).  Depths are explicit, so a
+# change of the default depth cannot change what a case runs.
+BACKEND_DEPTHS = [pytest.param(b, 1, id=b) for b in BACKENDS] + [
+    pytest.param("pallas", ITERS, id=f"pallas-fuse{ITERS}")]
+NO_3D_FUSION = "temporal fusion is 2D only (jacobi_fused.py)"
 DTYPES = {"f32": (jnp.float32, 2e-5), "bf16": (jnp.bfloat16, 6e-2)}
 
 
@@ -79,9 +86,9 @@ def _oracle(spec, x):
 @pytest.mark.slow
 @pytest.mark.parametrize("dtype_name", list(DTYPES))
 @pytest.mark.parametrize("mode", MODES, ids=lambda m: m.value)
-@pytest.mark.parametrize("backend", BACKENDS)
+@pytest.mark.parametrize("backend, fuse", BACKEND_DEPTHS)
 @pytest.mark.parametrize("family", list(SPECS))
-def test_matrix_cell(family, backend, mode, dtype_name):
+def test_matrix_cell(family, backend, fuse, mode, dtype_name):
     spec = SPECS[family]
     grid = GRIDS[spec.ndim]
     dtype, atol = DTYPES[dtype_name]
@@ -90,10 +97,14 @@ def test_matrix_cell(family, backend, mode, dtype_name):
                           bc=BC_VALUE)
     if not sup:
         pytest.skip(f"{backend}/{family}/{mode.value}: {sup.reason}")
+    if fuse > 1 and spec.ndim != 2:
+        pytest.skip(f"{backend}/{family}: {NO_3D_FUSION}")
 
     x = jnp.asarray(RNG.standard_normal((2, *grid)), dtype)
-    out = stencil_apply(spec, x, backend=backend, bc=BC_VALUE, mode=mode,
-                        iters=ITERS)
+    plan = make_plan(spec, grid, backend=backend, bc=BC_VALUE, mode=mode,
+                     iters=ITERS, fuse=fuse, dtype=dtype)
+    assert plan.fuse == fuse
+    out = plan(x)
     assert out.dtype == dtype
     ref = _oracle(spec, x)
     np.testing.assert_allclose(out.astype(jnp.float32), ref, atol=atol,
@@ -104,15 +115,22 @@ def test_matrix_cell(family, backend, mode, dtype_name):
 class TestRawZeroPad:
     """bc=None cells: raw repeated application with implicit zero padding."""
 
-    @pytest.mark.parametrize("backend", ["reference", "pallas", "pallas_fused"])
+    @pytest.mark.parametrize("backend, fuse", [
+        ("reference", 1), ("pallas", 1), ("pallas", ITERS)],
+        ids=["reference", "pallas", f"pallas-fuse{ITERS}"])
     @pytest.mark.parametrize("ndim", [2, 3])
-    def test_raw_matches_oracle(self, backend, ndim):
+    def test_raw_matches_oracle(self, backend, fuse, ndim):
         spec = laplace_jacobi(ndim)
         sup = backend_support(backend, spec, grid_shape=GRIDS[ndim], bc=None)
         if not sup:
             pytest.skip(sup.reason)
+        if fuse > 1 and ndim != 2:
+            pytest.skip(NO_3D_FUSION)
         x = jnp.asarray(RNG.standard_normal((1, *GRIDS[ndim])), jnp.float32)
-        out = stencil_apply(spec, x, backend=backend, bc=None, iters=ITERS)
+        plan = make_plan(spec, GRIDS[ndim], backend=backend, bc=None,
+                         iters=ITERS, fuse=fuse)
+        assert plan.fuse == fuse
+        out = plan(x)
         ref = stencil_apply(spec, x, backend="reference", bc=None, iters=ITERS)
         np.testing.assert_allclose(out, ref, atol=1e-5)
 
@@ -139,26 +157,25 @@ class TestAutoBackend:
 
     def test_auto_choice_is_supported_and_deterministic(self):
         spec = laplace_jacobi(2)
-        a, costs = choose_backend(spec, (64, 64), iters=20)
-        b, _ = choose_backend(spec, (64, 64), iters=20)
+        a = choose_schedule(spec, (64, 64), chunk=20)
+        b = choose_schedule(spec, (64, 64), chunk=20)
         assert a == b
-        assert backend_support(a, spec, grid_shape=(64, 64)).ok
-        assert costs[a] == min(costs.values())
+        assert backend_support(a.backend, spec, grid_shape=(64, 64)).ok
+        assert a.costs[a.backend] == min(a.costs.values())
 
     def test_auto_cost_model_device_kinds(self):
         # CPU must never pick interpret-mode Pallas; TPU should exploit
         # temporal fusion for iteration-heavy 2D runs (DESIGN §2).
         spec = laplace_jacobi(2)
-        cpu_choice, _ = choose_backend(spec, (64, 64), iters=20,
-                                       device_kind="cpu")
-        assert cpu_choice not in ("pallas", "pallas_fused")
-        tpu_choice, _ = choose_backend(spec, (64, 64), iters=20,
-                                       device_kind="TPU v5 lite")
-        assert tpu_choice == "pallas_fused"
+        cpu = choose_schedule(spec, (64, 64), chunk=20, device_kind="cpu")
+        assert cpu.backend != "pallas"
+        tpu = choose_schedule(spec, (64, 64), chunk=20,
+                              device_kind="TPU v5 lite")
+        assert tpu.backend == "pallas" and tpu.fuse > 1
 
     def test_auto_1d_falls_back_to_a_legal_backend(self):
         spec = causal_conv1d_spec([0.1, 0.2, 0.3, 0.4])
-        name, _ = choose_backend(spec, (64,), iters=4)
+        name = choose_schedule(spec, (64,), chunk=4).backend
         assert backend_support(name, spec, grid_shape=(64,)).ok
 
 
@@ -174,8 +191,11 @@ class TestDispatcherContract:
     def test_unknown_backend_rejected(self):
         spec = laplace_jacobi(2)
         x = jnp.zeros((1, 8, 8), jnp.float32)
-        with pytest.raises(ValueError, match="unknown backend"):
-            stencil_apply(spec, x, backend="tensorflow")
+        for name in ("tensorflow", "pallas_fused"):
+            with pytest.raises(ValueError, match="unknown backend") as err:
+                stencil_apply(spec, x, backend=name)
+            assert all(b in str(err.value) for b in BACKENDS)
+        assert len(BACKENDS) == 6
 
     def test_unsupported_cell_raises_with_reason(self):
         spec = star(2, [0.1, 0.05])  # radius 2
@@ -208,11 +228,11 @@ class TestVariableCoefficientSupport:
         # operand sliced per in-kernel iteration, so the cell is live — and
         # must match the oracle at a fuse depth > 1.
         spec = SPECS["varcoef/2d"]
-        sup = backend_support("pallas_fused", spec, grid_shape=GRIDS[2],
+        sup = backend_support("pallas", spec, grid_shape=GRIDS[2],
                               bc=BC_VALUE)
         assert sup.ok, sup.reason
         x = jnp.asarray(RNG.standard_normal((2, *GRIDS[2])), jnp.float32)
-        out = stencil_apply(spec, x, backend="pallas_fused", bc=BC_VALUE,
+        out = stencil_apply(spec, x, backend="pallas", bc=BC_VALUE,
                             iters=ITERS, fuse=ITERS)
         np.testing.assert_allclose(out, _oracle(spec, x), atol=2e-5)
 
@@ -224,7 +244,8 @@ class TestVariableCoefficientSupport:
         sup = backend_support("halo", spec, grid_shape=GRIDS[2], bc=BC_VALUE)
         assert sup.ok, sup.reason
         x = jnp.asarray(RNG.standard_normal((2, *GRIDS[2])), jnp.float32)
-        out = stencil_apply(spec, x, backend="halo", bc=BC_VALUE, iters=ITERS)
+        out = stencil_apply(spec, x, backend="halo", bc=BC_VALUE, iters=ITERS,
+                            fuse=1)
         np.testing.assert_allclose(out, _oracle(spec, x), atol=2e-5)
 
     def test_conv_3d_channels_reports_reasoned_skip(self):

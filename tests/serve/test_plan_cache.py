@@ -35,6 +35,7 @@ from repro.core import (
     set_default_plan_cache,
     solve,
 )
+from repro.core.autotune import TunedEntry, TunedTable, dtype_key, spec_family
 from repro.core.boundary import BoundaryMode
 
 GRID = (12, 12)
@@ -246,6 +247,30 @@ class TestLifecycle:
         assert s.backend in ("reference", "conv")
         assert cache.stats.probe_seconds > 0.0
         assert s.solve(_x0((8, 8))).converged
+
+
+class TestBucketBackend:
+    def test_measured_candidate_beats_faster_non_candidate(self):
+        # Without the probe a bucket is routed by choose_schedule over its
+        # candidates (reference, conv): a measured candidate wins even where
+        # a backend the bucket cannot serve (dense) measured faster, and the
+        # roofline (which picks reference here) is not asked.
+        bucket = (16, 16)
+        offsets = ((0, 1), (0, -1), (1, 0), (-1, 0))
+        template = _cache()._template(offsets, bucket)
+
+        def row(backend, us):
+            return TunedEntry(device_kind="cpu", family=spec_family(template),
+                              bucket=bucket, dtype=dtype_key(jnp.float32),
+                              backend=backend, us_per_iter=us)
+
+        def pick(tuned):
+            return _cache(tuned=tuned)._bucket_backend(
+                template, bucket, jnp.float32, None, "cpu")
+
+        assert pick(None) == "reference"
+        assert pick(TunedTable([row("dense", 0.5), row("conv", 5.0)])) == \
+            "conv"
 
 
 class TestConcurrency:

@@ -133,7 +133,7 @@ class TestForwardAgreement:
     def test_rejects_non_differentiable_backend(self):
         with pytest.raises(ValueError, match="differentiable"):
             implicit_solve(laplace_jacobi(2), jnp.zeros(GRID, jnp.float32),
-                           backend="pallas_fused")
+                           backend="pallas")
 
     def test_auto_backend_is_differentiable(self):
         for nd, grid in ((1, (33,)), (2, GRID)):

@@ -1,7 +1,7 @@
 """Autotuner tier: the measured tuned-table dispatch contract.
 
-Pins down (a) measured entries beating the roofline in ``choose_backend`` /
-``make_plan`` / ``select_fuse``, (b) the explicit roofline fallback when no
+Pins down (a) measured entries beating the roofline in ``choose_schedule``
+(which ``make_plan`` and ``Solver`` call), (b) the explicit roofline fallback when no
 entry applies, (c) corrupt / stale tables degrading with a warning instead
 of crashing dispatch, (d) interpret-mode measurements never winning a cell,
 (e) the extended fusion geometry (rim="resident") staying exact, and (f) the
@@ -18,7 +18,7 @@ import pytest
 
 from repro.core import (
     DirichletBC,
-    choose_backend,
+    choose_schedule,
     laplace_jacobi,
     make_plan,
     stencil_apply,
@@ -36,7 +36,6 @@ from repro.core.autotune import (
     validate_table,
 )
 from repro.core.reference import jacobi_reference
-from repro.core.solver import select_fuse
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -93,34 +92,37 @@ class TestCellKeys:
 class TestMeasuredPreference:
     def test_choose_backend_prefers_measured_entry_over_roofline(self):
         # The roofline on CPU picks conv for this cell; a measured table
-        # claiming a compiled pallas_fused schedule is faster must override.
-        roof_name, _ = choose_backend(SPEC, GRID, iters=100,
-                                      device_kind="cpu", tuned=None)
-        assert roof_name == "conv"
+        # claiming a compiled fused pallas schedule is faster must override.
+        roof = choose_schedule(SPEC, GRID, chunk=100, device_kind="cpu",
+                               tuned=None)
+        assert roof.backend == "conv" and roof.source == "roofline"
         table = TunedTable((entry("conv", 100.0),
-                            entry("pallas_fused", 5.0, fuse=8, block_h=64)))
-        name, costs = choose_backend(SPEC, GRID, iters=100,
-                                     device_kind="cpu", tuned=table)
-        assert name == "pallas_fused"
+                            entry("pallas", 5.0, fuse=8, block_h=64)))
+        got = choose_schedule(SPEC, GRID, chunk=100, device_kind="cpu",
+                              tuned=table)
+        assert got.backend == "pallas" and got.source == "tuned"
         # the returned cost table is the measured one, argmin included
-        assert costs[name] == min(costs.values())
-        assert costs["pallas_fused"] == pytest.approx(5e-6 * 100)
+        assert got.costs["pallas"] == min(got.costs.values())
+        assert got.costs["pallas"] == pytest.approx(5e-6 * 100)
+        # and the entry's schedule comes with it, the depth clamped to a
+        # divisor of the chunk
+        assert (got.fuse, got.block_h, got.rim) == (5, 64, "trapezoid")
 
     def test_make_plan_inherits_tuned_schedule(self):
         table = TunedTable((entry("conv", 100.0),
-                            entry("pallas_fused", 5.0, fuse=8, block_h=64,
+                            entry("pallas", 5.0, fuse=8, block_h=64,
                                   rim="trapezoid")))
         plan = make_plan(SPEC, GRID, backend="auto", bc=1.0, iters=16,
                          device_kind="cpu", tuned=table)
         assert plan.source == "tuned"
-        assert plan.backend == "pallas_fused"
+        assert plan.backend == "pallas"
         assert plan.fuse == 8 and plan.rim == "trapezoid"
 
     def test_tuned_fuse_not_inherited_when_it_does_not_divide(self):
-        table = TunedTable((entry("pallas_fused", 5.0, fuse=8),))
+        table = TunedTable((entry("pallas", 5.0, fuse=8),))
         plan = make_plan(SPEC, GRID, backend="auto", bc=1.0, iters=12,
                          device_kind="cpu", tuned=table)
-        assert plan.backend == "pallas_fused"
+        assert plan.backend == "pallas"
         assert 12 % plan.fuse == 0  # fell back to a legal depth
 
     def test_solver_plan_reports_choice_source(self):
@@ -136,18 +138,37 @@ class TestMeasuredPreference:
                    max_iters=4, device_kind="cpu", tuned=None)
         assert s.plan.source == "explicit"
 
+    def test_one_table_read_a_schedule(self):
+        # the Solver decides its whole schedule from one lookup of the cell
+        class Counted(TunedTable):
+            reads = 0
+
+            def lookup_cell(self, *args, **kw):
+                Counted.reads += 1
+                return super().lookup_cell(*args, **kw)
+
+        from repro.core import Solver
+        table = Counted((entry("conv", 10.0),
+                         entry("pallas", 5.0, fuse=4, block_h=64)))
+        s = Solver(SPEC, GRID, bc=1.0, rtol=1e-5, check_every=16,
+                   max_iters=64, device_kind="cpu", tuned=table)
+        assert Counted.reads == 1
+        assert (s.backend, s.fuse, s.plan.source) == ("pallas", 4, "tuned")
+
     def test_select_fuse_takes_measured_depth_with_clamping(self):
-        table = TunedTable((entry("pallas_fused", 5.0, fuse=8),))
-        assert select_fuse("pallas_fused", SPEC, GRID, 16, "cpu",
-                           tuned=table) == 8
+        table = TunedTable((entry("pallas", 5.0, fuse=8),))
+
+        def depth(backend, chunk):
+            return choose_schedule(SPEC, GRID, backend=backend, chunk=chunk,
+                                   device_kind="cpu", tuned=table).fuse
+        assert depth("pallas", 16) == 8
         # clamped down to a divisor of check_every
-        assert select_fuse("pallas_fused", SPEC, GRID, 20, "cpu",
-                           tuned=table) == 5
-        # non-fusing backends stay None regardless of the table
-        assert select_fuse("conv", SPEC, GRID, 16, "cpu", tuned=table) is None
+        assert depth("pallas", 20) == 5
+        # non-fusing backends stay at 1 regardless of the table
+        assert depth("conv", 16) == 1
 
     def test_tuned_plan_still_matches_oracle(self):
-        table = TunedTable((entry("pallas_fused", 5.0, fuse=4,
+        table = TunedTable((entry("pallas", 5.0, fuse=4,
                                   rim="resident"),))
         x = jnp.asarray(np.random.default_rng(0).standard_normal((8, 8)),
                         jnp.float32)
@@ -164,43 +185,42 @@ class TestMeasuredPreference:
 
 class TestRooflineFallback:
     def test_empty_table_matches_disabled_table(self):
-        a = choose_backend(SPEC, GRID, iters=100, device_kind="cpu",
-                           tuned=TunedTable())
-        b = choose_backend(SPEC, GRID, iters=100, device_kind="cpu",
-                           tuned=None)
+        a = choose_schedule(SPEC, GRID, chunk=100, device_kind="cpu",
+                            tuned=TunedTable())
+        b = choose_schedule(SPEC, GRID, chunk=100, device_kind="cpu",
+                            tuned=None)
         assert a == b
 
     def test_far_bucket_falls_back_to_roofline(self):
         # Entry recorded at 64x64; a 4096x4096 query is 12 doublings away —
         # outside the default max_distance — so the roofline decides.
-        table = TunedTable((entry("pallas_fused", 5.0, fuse=8),))
-        name, _ = choose_backend(SPEC, (4096, 4096), iters=100,
-                                 device_kind="cpu", tuned=table)
-        roof, _ = choose_backend(SPEC, (4096, 4096), iters=100,
-                                 device_kind="cpu", tuned=None)
-        assert name == roof == "conv"
+        table = TunedTable((entry("pallas", 5.0, fuse=8),))
+        got = choose_schedule(SPEC, (4096, 4096), chunk=100,
+                              device_kind="cpu", tuned=table)
+        roof = choose_schedule(SPEC, (4096, 4096), chunk=100,
+                               device_kind="cpu", tuned=None)
+        assert got == roof and got.backend == "conv"
 
     def test_near_bucket_transfers(self):
-        table = TunedTable((entry("pallas_fused", 5.0, fuse=8),))
-        name, _ = choose_backend(SPEC, (60, 60), iters=8, device_kind="cpu",
-                                 tuned=table)  # same bucket
-        assert name == "pallas_fused"
-        name, _ = choose_backend(SPEC, (100, 100), iters=8,
-                                 device_kind="cpu", tuned=table)  # 1 away
-        assert name == "pallas_fused"
+        table = TunedTable((entry("pallas", 5.0, fuse=8),))
+        got = choose_schedule(SPEC, (60, 60), chunk=8, device_kind="cpu",
+                              tuned=table)  # same bucket
+        assert got.backend == "pallas" and got.source == "tuned"
+        got = choose_schedule(SPEC, (100, 100), chunk=8, device_kind="cpu",
+                              tuned=table)  # 1 away
+        assert got.backend == "pallas" and got.source == "tuned"
 
     def test_wrong_family_or_dtype_misses(self):
         # The entry is keyed (cpu, 2d/r1/t4, fp32): a 3D query or a bf16
         # query must behave exactly as if the table were disabled.
-        table = TunedTable((entry("pallas_fused", 5.0),))
-        name, _ = choose_backend(laplace_jacobi(3), (8, 16, 16), iters=8,
-                                 device_kind="cpu", tuned=table)
-        assert name == choose_backend(laplace_jacobi(3), (8, 16, 16),
-                                      iters=8, device_kind="cpu",
-                                      tuned=None)[0]
-        name, _ = choose_backend(SPEC, GRID, iters=8, device_kind="cpu",
-                                 dtype=jnp.bfloat16, tuned=table)
-        assert name == "conv"
+        table = TunedTable((entry("pallas", 5.0),))
+        got = choose_schedule(laplace_jacobi(3), (8, 16, 16), chunk=8,
+                              device_kind="cpu", tuned=table)
+        assert got == choose_schedule(laplace_jacobi(3), (8, 16, 16),
+                                      chunk=8, device_kind="cpu", tuned=None)
+        got = choose_schedule(SPEC, GRID, chunk=8, device_kind="cpu",
+                              dtype=jnp.bfloat16, tuned=table)
+        assert got.backend == "conv" and got.source == "roofline"
 
 
 # ---------------------------------------------------------------------------
@@ -211,17 +231,18 @@ class TestInterpretedExclusion:
     def test_interpreted_entry_cannot_win_cell(self):
         table = TunedTable((entry("pallas", 1.0, interpreted=True),
                             entry("conv", 50.0)))
-        name, costs = choose_backend(SPEC, GRID, iters=8, device_kind="cpu",
-                                     tuned=table)
-        assert name == "conv"
-        assert "pallas" not in costs
+        got = choose_schedule(SPEC, GRID, chunk=8, device_kind="cpu",
+                              tuned=table)
+        assert got.backend == "conv"
+        assert "pallas" not in got.costs
 
     def test_only_interpreted_entries_fall_back_to_roofline(self):
         table = TunedTable((entry("pallas", 1.0, interpreted=True),
-                            entry("pallas_fused", 1.0, interpreted=True)))
-        name, _ = choose_backend(SPEC, GRID, iters=100, device_kind="cpu",
-                                 tuned=table)
-        assert name == "conv"  # roofline fallback, not interpreted pallas
+                            entry("pallas", 1.0, fuse=4, interpreted=True)))
+        got = choose_schedule(SPEC, GRID, chunk=100, device_kind="cpu",
+                              tuned=table)
+        # roofline fallback, not interpreted pallas
+        assert got.backend == "conv" and got.source == "roofline"
 
     def test_table_lookup_skips_interpreted(self):
         table = TunedTable((entry("pallas", 1.0, interpreted=True),
@@ -242,9 +263,9 @@ class TestTableRobustness:
             table = TunedTable.load(str(p))
         assert len(table) == 0
         # dispatch through the bad table still works (roofline fallback)
-        name, _ = choose_backend(SPEC, GRID, iters=100, device_kind="cpu",
-                                 tuned=table)
-        assert name == "conv"
+        got = choose_schedule(SPEC, GRID, chunk=100, device_kind="cpu",
+                              tuned=table)
+        assert got.backend == "conv"
 
     def test_stale_schema_warns_and_degrades(self, tmp_path):
         p = tmp_path / "TUNED_stencil.json"
@@ -279,13 +300,12 @@ class TestTableRobustness:
 
     def test_roundtrip(self, tmp_path):
         table = TunedTable((entry("conv", 50.0),
-                            entry("pallas_fused", 5.0, fuse=8, block_h=128,
+                            entry("pallas", 5.0, fuse=8, block_h=128,
                                   rim="trapezoid")))
         p = tmp_path / "t.json"
         table.save(str(p))
         back = TunedTable.load(str(p))
-        assert sorted(e.backend for e in back.entries) == \
-            ["conv", "pallas_fused"]
+        assert sorted(e.backend for e in back.entries) == ["conv", "pallas"]
         assert back.lookup("cpu", FAM, GRID, F32).fuse == 8
 
 
@@ -365,15 +385,16 @@ class TestMeshKeyedEntries:
         t.add(TunedEntry(device_kind="cpu", family=FAM, bucket=GRID,
                          dtype=F32, backend="halo", us_per_iter=3.0,
                          fuse=8, mesh=(2, 4)))
-        f = select_fuse("halo", SPEC, GRID, 16, "cpu", tuned=t, mesh=(2, 4))
-        assert f == 8
+        def depth(grid, chunk):
+            return choose_schedule(SPEC, grid, backend="halo", chunk=chunk,
+                                   device_kind="cpu", tuned=t,
+                                   mesh=(2, 4)).fuse
+        assert depth(GRID, 16) == 8
         # clamped to a divisor of check_every
-        assert select_fuse("halo", SPEC, GRID, 12, "cpu", tuned=t,
-                           mesh=(2, 4)) == 6
+        assert depth(GRID, 12) == 6
         # and to the depth the local tile can host: (8, 8) over (2, 4)
         # leaves 4x2 tiles, so the measured 8 collapses to 2
-        assert select_fuse("halo", SPEC, (8, 8), 16, "cpu", tuned=t,
-                           mesh=(2, 4)) == 2
+        assert depth((8, 8), 16) == 2
 
     def test_halo_schedule_candidates_respect_tile_and_chunk(self):
         from repro.core.autotune import halo_schedule_candidates
@@ -427,7 +448,7 @@ class TestResidentRim:
         assert _pallas_fits(SPEC, (64, 64), 32, v5e, rim="resident")
         assert not _pallas_fits(SPEC, (4096, 4096), 32, v5e, rim="resident")
         with pytest.raises(ValueError, match="scoped VMEM"):
-            make_plan(SPEC, (4096, 4096), backend="pallas_fused", bc=1.0,
+            make_plan(SPEC, (4096, 4096), backend="pallas", bc=1.0,
                       iters=32, fuse=32, rim="resident",
                       device_kind="TPU v5 lite")
 

@@ -92,15 +92,18 @@ class TestAnalyticHeatDecay:
     N = 16
     C = 0.15
 
-    @pytest.mark.parametrize(
-        "backend", ["reference", "conv", "pallas", "pallas_fused"])
-    def test_fixed_iteration_decay_rate(self, backend):
+    @pytest.mark.parametrize("backend, fuse", [
+        ("reference", None), ("conv", None), ("pallas", None),
+        ("pallas", 4)], ids=["reference", "conv", "pallas", "pallas-fuse4"])
+    def test_fixed_iteration_decay_rate(self, backend, fuse):
         v0 = heat_mode(self.N)
         mu = self._mu()
         k = 120
         res = solve(heat_spec(self.C), jnp.asarray(v0), backend=backend,
-                    bc=0.0, rtol=None, atol=None, max_iters=k)
+                    bc=0.0, rtol=None, atol=None, max_iters=k, fuse=fuse)
         assert res.iterations == k and not res.converged
+        if fuse is not None:
+            assert res.fuse == fuse
         np.testing.assert_allclose(np.asarray(res.x), mu**k * v0, atol=1e-3)
 
     @pytest.mark.parametrize("backend", ["reference", "conv", "pallas"])
@@ -198,9 +201,9 @@ class TestChunkingInvariance:
 
     def test_fuse_depth_invariance_converged(self):
         x0 = jnp.asarray(RNG.standard_normal((16, 16)), jnp.float32)
-        a = solve(laplace_jacobi(2), x0, backend="pallas_fused", bc=1.0,
+        a = solve(laplace_jacobi(2), x0, backend="pallas", bc=1.0,
                   rtol=1e-6, check_every=16, max_iters=2000, fuse=1)
-        b = solve(laplace_jacobi(2), x0, backend="pallas_fused", bc=1.0,
+        b = solve(laplace_jacobi(2), x0, backend="pallas", bc=1.0,
                   rtol=1e-6, check_every=16, max_iters=2000, fuse=8)
         assert a.iterations == b.iterations
         assert b.fuse == 8

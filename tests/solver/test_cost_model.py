@@ -9,9 +9,8 @@ import os
 
 import pytest
 
-from repro.core import box, choose_backend, laplace_jacobi
+from repro.core import box, choose_schedule, laplace_jacobi
 from repro.core.plan import DEVICE_PROFILES, estimate_seconds
-from repro.core.solver import select_fuse
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 BENCH_PATH = os.path.join(REPO, "BENCH_stencil.json")
@@ -44,34 +43,36 @@ class TestRooflineModel:
         assert dense > 10 * conv, (dense, conv)
 
     def test_auto_picks_conv_for_fp32_table1_shape_on_cpu(self):
-        name, costs = choose_backend(laplace_jacobi(2), TABLE1_GRID,
-                                     iters=TABLE1_ITERS, device_kind="cpu")
-        assert name == "conv", costs
+        got = choose_schedule(laplace_jacobi(2), TABLE1_GRID,
+                              chunk=TABLE1_ITERS, device_kind="cpu")
+        assert got.backend == "conv", got.costs
 
     def test_fuse_depth_pricing_is_monotone_while_memory_bound(self):
         # On the TPU profile a large 2D Jacobi is HBM-bound: each doubling of
         # the fuse depth halves traffic and must price cheaper.
         spec = laplace_jacobi(2)
         tpu = DEVICE_PROFILES["TPU v5 lite"]
-        ests = [estimate_seconds("pallas_fused", spec, (512, 512), 64, tpu,
+        ests = [estimate_seconds("pallas", spec, (512, 512), 64, tpu,
                                  fuse=f) for f in (1, 2, 4, 8)]
         assert ests == sorted(ests, reverse=True), ests
 
-    @pytest.mark.parametrize("grid, want", [((512, 512), 8),
-                                            ((4096, 16384), 4)],
+    @pytest.mark.parametrize("grid, want", [((512, 512), 4),
+                                            ((4096, 16384), 2)],
                              ids=["fits", "vmem-bound"])
     def test_default_fuse_priced_is_fuse_planned(self, grid, want):
-        # fuse=None prices the depth make_plan resolves, which the VMEM
-        # budget lowers on wide rows of a radius-2 star (8 does not fit).
+        # fuse=None prices the depth make_plan resolves: the roofline's
+        # cheapest that fits, where a radius-2 star's rim recompute stops
+        # deeper passes paying, and which the VMEM budget lowers on wide
+        # rows (8 does not fit).
         from repro.core import make_plan, star
         spec = star(2, [0.1, 0.1])
-        plan = make_plan(spec, grid, backend="pallas_fused", bc=1.0,
+        plan = make_plan(spec, grid, backend="pallas", bc=1.0,
                          iters=16, device_kind="TPU v5 lite",
                          interpret=True, tuned=None)
         assert plan.fuse == want
         tpu = DEVICE_PROFILES["TPU v5 lite"]
-        assert estimate_seconds("pallas_fused", spec, grid, 16, tpu) == \
-            estimate_seconds("pallas_fused", spec, grid, 16, tpu, fuse=want)
+        assert estimate_seconds("pallas", spec, grid, 16, tpu) == \
+            estimate_seconds("pallas", spec, grid, 16, tpu, fuse=want)
 
     def test_fuse_pricing_includes_rim_recompute(self):
         # Deeper fusion is NOT free on a grid that tiles into row blocks:
@@ -118,32 +119,37 @@ class TestRooflineModel:
         spec = laplace_jacobi(2)
         cpu = DEVICE_PROFILES["cpu"]
         body = estimate_seconds("reference", spec, (64, 64), 16, cpu)
-        halo = estimate_seconds("halo", spec, (64, 64), 16, cpu)
+        halo = estimate_seconds("halo", spec, (64, 64), 16, cpu, fuse=1)
         assert abs(halo - (body + 1e-5 * 16)) < 1e-12, (halo, body)
 
     def test_select_fuse_picks_deep_halo_on_latency_dominated_cells(self):
         spec = laplace_jacobi(2)
-        f = select_fuse("halo", spec, (64, 64), 16, "cpu", tuned=None,
-                        mesh=(2, 4))
-        assert f is not None and f > 1, f
+
+        def depth(grid):
+            return choose_schedule(spec, grid, backend="halo", chunk=16,
+                                   device_kind="cpu", tuned=None,
+                                   mesh=(2, 4)).fuse
+        f = depth((64, 64))
+        assert f > 1, f
         # the depth is clamped to what the local tile can host
-        f_small = select_fuse("halo", spec, (8, 8), 16, "cpu", tuned=None,
-                              mesh=(2, 4))
-        assert f_small is not None and f_small * spec.radius <= 2, f_small
+        f_small = depth((8, 8))
+        assert f_small * spec.radius <= 2, f_small
 
     def test_select_fuse_prefers_depth_on_tpu_not_on_cpu(self):
         spec = laplace_jacobi(2)
         # memory-bound TPU cell: fusion wins until rim recompute crosses the
         # HBM saving (the model finds the crossover, not the deepest depth)
-        assert select_fuse("pallas_fused", spec, (512, 512), 16,
-                           "TPU v5 lite") > 1
+        def depth(backend, spec, grid, device_kind):
+            return choose_schedule(spec, grid, backend=backend, chunk=16,
+                                   device_kind=device_kind).fuse
+        assert depth("pallas", spec, (512, 512), "TPU v5 lite") > 1
         # compute-bound CPU cell (9-point box on a grid that tiles into row
         # blocks): fusing only adds rim recompute
-        assert select_fuse("pallas", box(2), (4096, 4096), 16, "cpu") == 1
+        assert depth("pallas", box(2), (4096, 4096), "cpu") == 1
         # non-fusing backends and 3D kernels never fuse
-        assert select_fuse("conv", spec, (64, 64), 16, "cpu") is None
-        assert select_fuse("pallas", laplace_jacobi(3), (8, 16, 16), 16,
-                           "TPU v5 lite") is None
+        assert depth("conv", spec, (64, 64), "cpu") == 1
+        assert depth("pallas", laplace_jacobi(3), (8, 16, 16),
+                     "TPU v5 lite") == 1
 
 
 class TestDeviceIdentity:
@@ -156,9 +162,10 @@ class TestDeviceIdentity:
         with pytest.raises(ValueError, match="no device profile"):
             device_profile("TPU v99")
         with pytest.raises(ValueError, match="no device profile"):
-            choose_backend(spec, TABLE1_GRID, iters=8, device_kind="tpu")
+            choose_schedule(spec, TABLE1_GRID, chunk=8, device_kind="tpu")
         with pytest.raises(ValueError, match="no device profile"):
-            select_fuse("pallas_fused", spec, TABLE1_GRID, 16, "gpu")
+            choose_schedule(spec, TABLE1_GRID, backend="pallas", chunk=16,
+                            device_kind="gpu")
 
     def test_current_device_is_read_from_jax(self):
         import jax
@@ -172,12 +179,10 @@ class TestDeviceIdentity:
         from repro.core import backend_support
         spec = laplace_jacobi(2)
         wide = (64, 1 << 17)
-        for b in ("pallas", "pallas_fused"):
-            sup = backend_support(b, spec, grid_shape=wide)
-            assert not sup and "VMEM" in sup.reason, sup
-        name, _ = choose_backend(spec, wide, iters=8,
-                                 device_kind="TPU v5 lite")
-        assert name not in ("pallas", "pallas_fused")
+        sup = backend_support("pallas", spec, grid_shape=wide)
+        assert not sup and "VMEM" in sup.reason, sup
+        got = choose_schedule(spec, wide, chunk=8, device_kind="TPU v5 lite")
+        assert got.backend != "pallas"
         assert backend_support("pallas", spec, grid_shape=(8192, 8192))
 
     def test_v5e_peaks_are_the_published_ones(self):
@@ -212,8 +217,8 @@ class TestRecordedTimings:
         if not auto_keys:
             pytest.skip("artifact lacks an auto row")
         recorded = auto_keys[0].split("auto=")[1].split("/")[0]
-        name, _ = choose_backend(laplace_jacobi(2), TABLE1_GRID,
-                                 iters=TABLE1_ITERS, device_kind="cpu")
+        name = choose_schedule(laplace_jacobi(2), TABLE1_GRID,
+                               chunk=TABLE1_ITERS, device_kind="cpu").backend
         assert recorded == name, (recorded, name)
 
     def test_solver_rows_have_stable_schema(self):

@@ -147,7 +147,7 @@ class TestDistributedSolve:
         assert "varcoef-dist ok" in out
 
     def test_solver_auto_selects_legal_halo_fuse(self, run_with_devices):
-        # select_fuse must hand the solver a depth that divides check_every
+        # the schedule must hand the solver a depth that divides check_every
         # and fits the local tile — and the result still matches.
         out = run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np

@@ -98,18 +98,22 @@ def test_lane_blocks():
 @pytest.mark.parametrize("batch, tile, fuse", [
     (256, (64, 64), 4), (300, (16, 16), 2), (300, (32, 64), 1)])
 def test_jacobi2d_takes_the_lanes(batch, tile, fuse):
-    # the public wrapper routes these batches to the batch-minor kernel,
-    # and its scan of calls computes the oracle's sweeps
+    # a plan routes these batches to the batch-minor kernel through the
+    # public wrapper, and its scan of calls computes the oracle's sweeps
     x = _batch((batch, *tile), jnp.float32, seed=2)
     assert lane_axis(x.shape, SPEC, BC, budget=BUDGET) == "batch"
-    got = jacobi2d(x, SPEC, bc_value=BC, iterations=3 * fuse, fuse=fuse)
+    plan = make_plan(SPEC, tile, backend="pallas", bc=BC, iters=3 * fuse,
+                     fuse=fuse)
+    assert plan.lane_axis(batch) == "batch"
+    got = plan(x)
     np.testing.assert_allclose(np.asarray(got), np.asarray(
         _reference(x, SPEC, 3 * fuse)), rtol=0, atol=1e-6)
-    text = jax.jit(lambda v: jacobi2d(
-        v, SPEC, bc_value=BC, iterations=3 * fuse,
-        fuse=fuse)).lower(x).as_text()
-    assert "jacobi2d_lanes_step" in text
-    assert "jacobi2d_fused_step" not in text
+    for run in (lambda v: plan(v), lambda v: jacobi2d(
+            v, SPEC, bc_value=BC, iterations=3 * fuse, fuse=fuse,
+            lane_axis="batch")):
+        text = jax.jit(run).lower(x).as_text()
+        assert "jacobi2d_lanes_step" in text
+        assert "jacobi2d_fused_step" not in text
 
 
 @pytest.mark.parametrize("shape", [(131072, 64, 64), (4096, 64, 64),
@@ -159,9 +163,12 @@ def test_plan_records_the_lane_axis(grid, backend, bc, batch, want):
 def test_3d_and_small_batches_keep_their_kernels():
     # jacobi3d never asks the rule; a 2D batch under 128 runs the row kernel
     x = _batch((64, 16, 16), jnp.float32)
-    text = jax.jit(lambda v: jacobi2d(v, SPEC, bc_value=BC, iterations=4,
-                                      fuse=4)).lower(x).as_text()
-    assert "jacobi2d_fused_step" in text and "lanes" not in text
+    plan = make_plan(SPEC, (16, 16), backend="pallas", bc=BC, iters=4,
+                     fuse=4)
+    for run in (lambda v: plan(v), lambda v: jacobi2d(
+            v, SPEC, bc_value=BC, iterations=4, fuse=4)):
+        text = jax.jit(run).lower(x).as_text()
+        assert "jacobi2d_fused_step" in text and "lanes" not in text
     x3 = _batch((128, 4, 8, 8), jnp.float32)
     text = jax.jit(lambda v: jacobi3d(v, laplace_jacobi(3), bc_value=BC,
                                       iterations=2)).lower(x3).as_text()

@@ -52,7 +52,7 @@ class TestConfigAndApi:
 
     def test_rejects_non_differentiable_backend(self):
         with pytest.raises(ValueError, match="differentiable"):
-            SolverLayerConfig(backend="pallas_fused")
+            SolverLayerConfig(backend="pallas")
 
     def test_init_shapes_dims_agree(self):
         api = build(get_config("learned-stencil", smoke=True))

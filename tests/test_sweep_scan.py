@@ -56,7 +56,7 @@ CASES = {
         1.0),
     "raw2d-fuse2": (
         (2, 16, 16),
-        lambda n: _plan(SPEC2, (16, 16), n, backend="pallas_fused", fuse=2),
+        lambda n: _plan(SPEC2, (16, 16), n, backend="pallas", fuse=2),
         lambda x: jacobi2d_fused_step(x, SPEC2, fuse=2, rim="trapezoid"),
         None),
     "raw3d": (
